@@ -55,13 +55,7 @@ def run(workloads=("moonlight", "qwen2-vl-72b", "kimi-k2"), seed=0):
                      "within_2x_band": 1.2 <= full <= 3.2}
     save_result("e2e_throughput", {"rows": rows, "checks": checks,
                                    "table": txt})
-    try:
-        engine = ensure_engine_rollout_record()
-        ratio = engine["forward_invocation_ratio"]
-    except Exception as e:  # noqa: BLE001 - report-and-continue CLI
-        print(f"[e2e_throughput] engine rollout bench failed: {e}",
-              flush=True)
-        ratio = None
+    ratio = ensure_engine_rollout_record()["forward_invocation_ratio"]
     update_bench_rollout("e2e_throughput", {
         "tokens_per_sec": {k: v["tokens_per_sec"]
                            for k, v in record.items()},

@@ -51,14 +51,12 @@ def run(workloads=("moonlight", "qwen2-vl-72b", "kimi-k2"), seed=0):
     save_result("phase_split", {"rows": rows, "record": record,
                                 "table": txt})
     # rollout dominance is the motivation for the engine hot-path work;
-    # track it next to the engine numbers in BENCH_rollout.json.  The
-    # engine micro-bench must not take the simulator results down with it.
-    try:
-        ensure_engine_rollout_record()
-        ensure_engine_migration_record()
-        ensure_train_overlap_record()
-    except Exception as e:  # noqa: BLE001 - report-and-continue CLI
-        print(f"[phase_split] engine rollout bench failed: {e}", flush=True)
+    # track it next to the engine numbers in BENCH_rollout.json.  A
+    # failed engine bench fails the run (the simulator results are
+    # already saved above).
+    ensure_engine_rollout_record()
+    ensure_engine_migration_record()
+    ensure_train_overlap_record()
     update_bench_rollout("phase_split", {
         w: {"rollout_pct": record[w]["rollout_pct"]} for w in record})
     return record

@@ -67,11 +67,7 @@ def run(workloads=("moonlight", "qwen2-vl-72b", "kimi-k2"), seed=0):
                 "Fig. 11 — SD strategies (throughput + acceptance)")
     save_result("sd_strategies", {"rows": rows, "record": record,
                                   "table": txt})
-    try:
-        ensure_engine_tree_record()
-    except Exception as e:  # noqa: BLE001 - report-and-continue CLI
-        print(f"[sd_strategies] engine tree bench failed: {e}",
-              flush=True)
+    ensure_engine_tree_record()
     return record
 
 

@@ -175,6 +175,7 @@ class SeerRollout:
                  fetch_retries: int = 3,
                  fetch_backoff_s: float = 0.05,
                  tp: Optional[int] = None,
+                 devices: Optional[Sequence] = None,
                  tracer=None,
                  steps: Optional[StepFunctions] = None):
         self.cfg = cfg
@@ -218,6 +219,14 @@ class SeerRollout:
         self.tp = tp
         fwd = ForwardCostModel(cfg, TPU_V5E, tp=tp or 1)
         n_nodes = max(1, min(n_nodes, n_instances))
+        # placement: instance i runs on devices[i*k:(i+1)*k], k = tp or
+        # 1 (a device may repeat, e.g. several instances on one chip);
+        # None keeps the default layout (Instance docs)
+        per = tp or 1
+        if devices is not None and len(devices) != n_instances * per:
+            raise ValueError(
+                f"{n_instances} instances at tp={tp} need "
+                f"{n_instances * per} devices, given {len(devices)}")
         self.instances = [
             Instance(cfg, params, self.steps, max_slots=max_slots,
                      cache_len=cache_len, prefill_chunk=prefill_chunk,
@@ -230,6 +239,8 @@ class SeerRollout:
                      node=f"n{i * n_nodes // n_instances}",
                      admit_into_draining=admit_into_draining,
                      tp=tp,
+                     devices=None if devices is None
+                     else devices[i * per:(i + 1) * per],
                      base_seed=base_seed)
             for i in range(n_instances)
         ]

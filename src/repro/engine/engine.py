@@ -126,7 +126,7 @@ import time
 import zlib
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -146,9 +146,13 @@ _INT32_MAX = np.iinfo(np.int32).max
 
 def _sctx_key(sctx: Optional[ShardCtx]):
     """Step-cache key component for a sharding context.  Engine meshes
-    are cached per degree (``launch.mesh.engine_mesh``), so tp size is
-    the whole identity — instances of equal tp share compilations."""
-    return None if sctx is None else sctx.tp_size
+    are cached per (degree, devices) (``launch.mesh.engine_mesh``), so
+    those are the whole identity — instances of equal tp on the same
+    devices share compilations; a mesh on other devices needs its own
+    (its sharding annotations name those devices)."""
+    if sctx is None:
+        return None
+    return sctx.tp_size, tuple(d.id for d in sctx.mesh.devices.flat)
 
 _DONATION_SUPPORTED: Optional[bool] = None
 
@@ -759,6 +763,7 @@ class Instance:
 
     def __init__(self, cfg: ModelConfig, params, steps: StepFunctions, *,
                  tp: Optional[int] = None,
+                 devices: Optional[Sequence] = None,
                  max_slots: int = 8, cache_len: int = 4096,
                  prefill_chunk: int = 64, gamma_max: int = 8,
                  prefill_mode: str = "batched",
@@ -834,12 +839,24 @@ class Instance:
         # the degenerate meshed case: every constraint is a full-
         # replication annotation, so the step math is bit-identical to
         # tp=None (the oracle gate in check_bench.py asserts it).
+        #
+        # ``devices`` places the instance: one device for tp=None, the
+        # ``tp`` mesh devices otherwise.  None keeps the default layout
+        # (unmeshed: wherever params live, i.e. the default device;
+        # meshed: the first tp devices).
         self.tp = tp
+        if devices is not None and len(devices) != (tp or 1):
+            raise ValueError(
+                f"instance with tp={tp} given {len(devices)} devices")
+        # where blob imports land on an unmeshed instance
+        self.device = devices[0] if devices is not None and tp is None \
+            else jax.devices()[0]
         if tp is None:
             self._sctx: Optional[ShardCtx] = None
         else:
             from repro.launch.mesh import engine_mesh, make_engine_shard_ctx
-            self._sctx = make_engine_shard_ctx(engine_mesh(tp))
+            self._sctx = make_engine_shard_ctx(engine_mesh(
+                tp, None if devices is None else tuple(devices)))
         self.base_key = jax.random.PRNGKey(base_seed)
         self.cache = init_cache(cfg, max_slots, cache_len)
         if cfg.arch_type in ("vlm", "audio"):
@@ -856,6 +873,9 @@ class Instance:
                 params, engine_param_shardings(cfg, self._sctx))
             self.cache = jax.device_put(
                 self.cache, engine_cache_shardings(self._sctx, self.cache))
+        elif devices is not None:
+            self.params = jax.device_put(params, self.device)
+            self.cache = jax.device_put(self.cache, self.device)
         self.slots: List[Optional[EngineSeq]] = [None] * max_slots
         self._inflight: Optional[StepTicket] = None
         # liveness: a crashed instance refuses all work until replaced.
@@ -1245,18 +1265,18 @@ class Instance:
         operands live on a different mesh (or a single device) raises.
         Meshed target: commit every leaf replicated on our mesh — a
         cross-tp-degree re-place with no host sync.  Unmeshed target:
-        pull multi-device leaves down to the default device; already-
-        local leaves (and hand-built numpy blobs) pass through
-        untouched, keeping the tp=None path exactly as before."""
+        move leaves that live elsewhere (another instance's device or
+        mesh) to this instance's device; leaves already there (and
+        hand-built numpy blobs) pass through untouched."""
         if self._sctx is not None:
             sh = NamedSharding(self._sctx.mesh, P())
             return {k: jax.device_put(v, sh) for k, v in arrays.items()}
 
         def one(v):
             sharding = getattr(v, "sharding", None)
-            if sharding is None or len(sharding.device_set) <= 1:
+            if sharding is None or sharding.device_set == {self.device}:
                 return v
-            return jax.device_put(v, jax.devices()[0])
+            return jax.device_put(v, self.device)
 
         return {k: one(v) for k, v in arrays.items()}
 

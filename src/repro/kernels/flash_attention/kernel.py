@@ -89,7 +89,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_pallas(q, k, v, *, q_offset: int = 0,
                            causal: bool = True, window: int = 0,
                            block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True):
+                           interpret: bool):
     """q: (B,Tq,Hq,D); k,v: (B,Tk,Hk,D) -> (B,Tq,Hq,D)."""
     B, Tq, Hq, D = q.shape
     Tk, Hk = k.shape[1], k.shape[2]

@@ -48,14 +48,13 @@ def _verify_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
     q = q_ref[0].astype(jnp.float32) * scale               # (T, D)
     k = k_ref[0].astype(jnp.float32)                       # (bk, D)
     v = v_ref[0].astype(jnp.float32)
-    qp = qpos_ref[0]                                       # (T,)
-    kp = kpos_ref[0]                                       # (bk,)
+    qp = qpos_ref[...]                                     # (T, 1)
+    kp = kpos_ref[...]                                     # (1, bk)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (T, bk)
-    mask = jnp.logical_and(kp[None, :] >= 0,
-                           kp[None, :] <= qp[:, None])
+    mask = jnp.logical_and(kp >= 0, kp <= qp)
     if window:
-        mask = jnp.logical_and(mask, kp[None, :] > qp[:, None] - window)
+        mask = jnp.logical_and(mask, kp > qp - window)
     if tree_ref is not None:
         mask = jnp.logical_and(mask, tree_ref[0] != 0)     # (T, bk)
     s = jnp.where(mask, s, NEG_INF)
@@ -85,7 +84,7 @@ def _tree_kernel(qpos_ref, kpos_ref, tree_ref, q_ref, k_ref, v_ref,
 
 
 def spec_verify_pallas(q, k, v, q_pos, k_pos, *, window: int = 0,
-                       block_k: int = 128, interpret: bool = True):
+                       block_k: int = 128, interpret: bool):
     """q: (B,T,Hq,D); k,v: (B,S,Hk,D); q_pos: (B,T); k_pos: (B,S)."""
     return _verify_call(q, k, v, q_pos, k_pos, None, window=window,
                         block_k=block_k, interpret=interpret)
@@ -93,7 +92,7 @@ def spec_verify_pallas(q, k, v, q_pos, k_pos, *, window: int = 0,
 
 def tree_verify_pallas(q, k, v, q_pos, k_pos, tree_mask, *,
                        window: int = 0, block_k: int = 128,
-                       interpret: bool = True):
+                       interpret: bool):
     """Tree-verify attention: one fused pass over a draft token tree.
 
     Same contract as :func:`spec_verify_pallas` plus ``tree_mask``
@@ -140,19 +139,22 @@ def _verify_call(q, k, v, q_pos, k_pos, tree_mask, *, window: int,
         return (b * Hk + h // rep, ki, 0)
 
     def qpos_map(bh, ki):
-        return (bh // Hq, 0)
+        return (bh // Hq, 0, 0)
 
     def kpos_map(bh, ki):
-        return (bh // Hq, ki)
+        return (bh // Hq, 0, ki)
 
     def tree_map(bh, ki):
         return (bh // Hq, 0, ki)
 
+    # positions ride as a (T, 1) column and a (1, block_k) row: the
+    # mask is their broadcast compare, and each block's last two dims
+    # are whole or 128-aligned, as the TPU lowering requires
     in_specs = [
-        pl.BlockSpec((1, T), qpos_map),
-        pl.BlockSpec((1, block_k), kpos_map),
+        pl.BlockSpec((None, T, 1), qpos_map),
+        pl.BlockSpec((None, 1, block_k), kpos_map),
     ]
-    operands = [q_pos, k_pos]
+    operands = [q_pos.reshape(B, T, 1), k_pos.reshape(B, 1, Sp)]
     if tree_mask is None:
         kernel = functools.partial(_verify_kernel, scale=D ** -0.5,
                                    window=window, n_k=n_k)

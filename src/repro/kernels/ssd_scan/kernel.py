@@ -29,24 +29,29 @@ from jax.experimental import pallas as pl
 def _ssd_kernel(x_ref, dt_ref, dA_ref, b_ref, c_ref,
                 y_ref, s_ref, cs_ref, *, Q: int):
     x = x_ref[...].astype(jnp.float32)          # (Q, P)
-    dt = dt_ref[...].astype(jnp.float32)        # (Q,)
-    dA = dA_ref[...].astype(jnp.float32)        # (Q,)
+    dt = dt_ref[...].astype(jnp.float32)        # (1, Q) row
+    dA = dA_ref[...].astype(jnp.float32)        # (1, Q) row
     Bm = b_ref[...].astype(jnp.float32)         # (Q, N)
     Cm = c_ref[...].astype(jnp.float32)         # (Q, N)
 
-    cs = jnp.cumsum(dA)                         # (Q,) inclusive
-    seg = cs[:, None] - cs[None, :]             # (Q, Q)
-    tril = jax.lax.iota(jnp.int32, Q)[:, None] >= \
-        jax.lax.iota(jnp.int32, Q)[None, :]
-    L = jnp.where(tril, jnp.exp(seg), 0.0)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    # inclusive cumsum as a matmul with the upper-triangular ones
+    # (Mosaic has no cumsum); HIGHEST keeps the sums at full f32
+    cs = jax.lax.dot_general(dA, (rows <= cols).astype(jnp.float32),
+                             (((1,), (0,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
+                             preferred_element_type=jnp.float32)  # (1,Q)
+    seg = cs.T - cs                             # (Q, Q)
+    L = jnp.where(rows >= cols, jnp.exp(seg), 0.0)
     CB = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (Q,Q)
-    W = CB * L * dt[None, :]
+    W = CB * L * dt
     y = jax.lax.dot_general(W, x, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)   # (Q,P)
-    total = cs[Q - 1]
-    w_state = dt * jnp.exp(total - cs)          # (Q,)
-    S_loc = jax.lax.dot_general(Bm * w_state[:, None], x,
+    total = cs[:, Q - 1:]                       # (1, 1)
+    w_state = (dt * jnp.exp(total - cs)).T      # (Q, 1)
+    S_loc = jax.lax.dot_general(Bm * w_state, x,
                                 (((0,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (N,P)
     y_ref[...] = y
@@ -55,7 +60,7 @@ def _ssd_kernel(x_ref, dt_ref, dA_ref, b_ref, c_ref,
 
 
 def ssd_intra_chunk_pallas(xc, dtc, dAc, Bc, Cc, *, n_groups: int,
-                           interpret: bool = True):
+                           interpret: bool):
     """Intra-chunk terms for all chunks at once.
 
     xc:  (b, nc, Q, nh, P) f32     dtc/dAc: (b, nc, Q, nh)
@@ -67,8 +72,10 @@ def ssd_intra_chunk_pallas(xc, dtc, dAc, Bc, Cc, *, n_groups: int,
     Hg = nh // G
 
     xf = xc.transpose(0, 3, 1, 2, 4).reshape(b * nh, nc, Q, P)
-    dtf = dtc.transpose(0, 3, 1, 2).reshape(b * nh, nc, Q)
-    dAf = dAc.transpose(0, 3, 1, 2).reshape(b * nh, nc, Q)
+    # per-step planes ride as (1, Q) rows so each block's last two dims
+    # are whole, as the TPU lowering requires
+    dtf = dtc.transpose(0, 3, 1, 2).reshape(b * nh, nc, 1, Q)
+    dAf = dAc.transpose(0, 3, 1, 2).reshape(b * nh, nc, 1, Q)
     Bf = Bc.transpose(0, 3, 1, 2, 4).reshape(b * G, nc, Q, N)
     Cf = Cc.transpose(0, 3, 1, 2, 4).reshape(b * G, nc, Q, N)
 
@@ -80,17 +87,14 @@ def ssd_intra_chunk_pallas(xc, dtc, dAc, Bc, Cc, *, n_groups: int,
         h = bh % nh
         return (bb * G + h // Hg, ci, 0)
 
-    def h2_map(bh, ci):
-        return (bh, ci)
-
     kernel = functools.partial(_ssd_kernel, Q=Q)
     y, s, cs = pl.pallas_call(
         kernel,
         grid=(b * nh, nc),
         in_specs=[
             pl.BlockSpec((None, None, Q, P), lambda bh, ci: (bh, ci, 0, 0)),
-            pl.BlockSpec((None, None, Q), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((None, None, Q), lambda bh, ci: (bh, ci, 0)),
+            pl.BlockSpec((None, None, 1, Q), lambda bh, ci: (bh, ci, 0, 0)),
+            pl.BlockSpec((None, None, 1, Q), lambda bh, ci: (bh, ci, 0, 0)),
             pl.BlockSpec((None, None, Q, N),
                          lambda bh, ci: g_map(bh, ci) + (0,)),
             pl.BlockSpec((None, None, Q, N),
@@ -99,12 +103,12 @@ def ssd_intra_chunk_pallas(xc, dtc, dAc, Bc, Cc, *, n_groups: int,
         out_specs=[
             pl.BlockSpec((None, None, Q, P), lambda bh, ci: (bh, ci, 0, 0)),
             pl.BlockSpec((None, None, N, P), lambda bh, ci: (bh, ci, 0, 0)),
-            pl.BlockSpec((None, None, Q), lambda bh, ci: (bh, ci, 0)),
+            pl.BlockSpec((None, None, 1, Q), lambda bh, ci: (bh, ci, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * nh, nc, Q, P), jnp.float32),
             jax.ShapeDtypeStruct((b * nh, nc, N, P), jnp.float32),
-            jax.ShapeDtypeStruct((b * nh, nc, Q), jnp.float32),
+            jax.ShapeDtypeStruct((b * nh, nc, 1, Q), jnp.float32),
         ],
         interpret=interpret,
     )(xf, dtf, dAf, Bf, Cf)

@@ -11,11 +11,11 @@ pure data parallelism (gradient all-reduce crosses DCN/ICI between pods).
 from __future__ import annotations
 
 from functools import lru_cache
-from typing import Optional
+from typing import Optional, Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh
 
 from repro.sharding import ShardCtx
 
@@ -30,11 +30,23 @@ def _check_devices(needed: int, what: str) -> None:
             "(tests/conftest.py does this for tier-1)")
 
 
+def auto_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices: Optional[Sequence] = None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    ``make_mesh`` defaults to ``Explicit`` axes, and the model code's
+    activation annotations (``sharding.constrain`` →
+    ``with_sharding_constraint``) accept only ``Auto`` axes."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     _check_devices(int(np.prod(shape)), f"production mesh {shape}")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_shard_ctx(mesh: Mesh, *, train: bool,
@@ -50,24 +62,31 @@ def small_mesh(n_model: Optional[int] = None) -> Mesh:
     n = len(jax.devices())
     m = n_model or 1
     _check_devices(m, f"small mesh (model={m})")
-    return jax.make_mesh((n // m, m), ("data", "model"))
+    return auto_mesh((n // m, m), ("data", "model"))
 
 
 # -------- per-instance engine meshes ----------------------------------------
 
 @lru_cache(maxsize=None)
-def engine_mesh(tp: int) -> Mesh:
+def engine_mesh(tp: int, devices: Optional[tuple] = None) -> Mesh:
     """1-D tensor-parallel mesh for one rollout Instance.
 
     The engine shards over KV heads only (no data axis: the slot batch
     is tiny and rides replicated), so the mesh is just ``(tp,)`` over
-    the ``model`` axis.  Cached per degree — every tp=k instance shares
-    one Mesh object, so StepFunctions compilations are shared too.
+    the ``model`` axis, built over ``devices`` (default: the first
+    ``tp`` devices).  Cached per (degree, devices) — every instance on
+    the same devices shares one Mesh object, so StepFunctions
+    compilations are shared too.
     """
     if tp < 1:
         raise ValueError(f"tp must be >= 1, got {tp}")
-    _check_devices(tp, f"engine mesh (tp={tp})")
-    return jax.make_mesh((tp,), ("model",))
+    if devices is None:
+        _check_devices(tp, f"engine mesh (tp={tp})")
+        devices = tuple(jax.devices()[:tp])
+    elif len(devices) != tp:
+        raise ValueError(
+            f"engine mesh (tp={tp}) given {len(devices)} devices")
+    return auto_mesh((tp,), ("model",), devices=devices)
 
 
 def make_engine_shard_ctx(mesh: Mesh) -> ShardCtx:

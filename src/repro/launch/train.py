@@ -39,8 +39,10 @@ def main(argv=None):
 
     from repro.configs import get_config, get_tiny_config
     from repro.data.tasks import make_task
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.training import OptConfig, RLConfig, RLTrainer
 
+    enable_compile_cache()
     cfg = get_config(args.arch) if args.full else get_tiny_config(args.arch)
     if args.full and cfg.num_params() > 2e9:
         raise SystemExit("--full on a model >2B params needs a real cluster")

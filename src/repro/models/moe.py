@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.models.common import Builder, lin
-from repro.sharding import ShardCtx, constrain, resolve_shard_map
+from repro.sharding import ShardCtx, constrain
 
 
 def init_moe(b: Builder, d: int, eff: int, n_expert: int, n_shared: int):
@@ -247,12 +247,7 @@ def moe_forward(x, p, cfg, sctx: Optional[ShardCtx]):
     sd = p.get("sd", jnp.zeros((), x.dtype))
 
     y_spec = P(dp if dp else None, sctx.tp, None) if scatter else x_spec
-    shard_map = resolve_shard_map()
-    if shard_map is None:
-        raise RuntimeError(
-            "no shard_map in this jax (neither jax.shard_map nor "
-            "jax.experimental.shard_map) — MoE ep/tp dispatch needs it")
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, P(None, None), wg_spec, wg_spec, wd_spec,
                   *shared_specs),

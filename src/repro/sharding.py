@@ -15,38 +15,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-# -------- shard_map compat shim ---------------------------------------------
-# jax promoted shard_map out of jax.experimental at different versions;
-# this container's jax has only the experimental entry point.  Everything
-# in this repo resolves shard_map through here — never test
-# ``hasattr(jax, "shard_map")`` directly (that alias is absent on jax
-# versions where the experimental shard_map works fine).
-
-def resolve_shard_map():
-    """Return a shard_map callable with the modern keyword signature
-    ``shard_map(f, mesh=..., in_specs=..., out_specs=..., check_vma=...)``,
-    or None when jax has neither entry point.  The experimental function
-    spells the replication-check kwarg ``check_rep``; the wrapper
-    translates so call sites are version-agnostic."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn
-    try:
-        from jax.experimental.shard_map import shard_map as _exp
-    except ImportError:
-        return None
-
-    def _compat(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _exp(f, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs, check_rep=check_vma)
-
-    return _compat
-
-
-def shard_map_available() -> bool:
-    return resolve_shard_map() is not None
-
-
 # Logical axis vocabulary used by model init:
 #   layers        stacked-layer axis (never sharded)
 #   embed         d_model rows (FSDP target in train mode)

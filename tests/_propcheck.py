@@ -1,140 +1,8 @@
-"""Property-test shim: real ``hypothesis`` when installed, otherwise a
-minimal seeded-random fallback implementing the subset this repo uses.
+"""The property-test surface the 5 property modules import:
+``given``, ``settings`` and ``strategies`` (alias ``st``) from
+``hypothesis``."""
+from hypothesis import given, settings
+from hypothesis import strategies
+from hypothesis import strategies as st
 
-The fallback is NOT hypothesis — no shrinking, no example database — but
-the properties genuinely execute: each ``@given`` test runs
-``settings.max_examples`` iterations with examples drawn from a
-deterministically-seeded RNG (seed = crc32 of the test's qualified name),
-so failures are reproducible run-to-run and the falsifying example is
-attached to the raised error.
-
-Supported surface (everything the 5 property-test modules need):
-
-* ``given(*strategies, **strategies)`` — positional strategies bind to the
-  rightmost parameters (hypothesis semantics), keyword strategies by name;
-  remaining parameters stay visible to pytest for fixtures/parametrize.
-* ``settings(max_examples=, deadline=)`` — either decorator order.
-* ``strategies.integers / floats / lists / sampled_from / data``.
-"""
-from __future__ import annotations
-
-try:                                        # pragma: no cover - env dependent
-    from hypothesis import given, settings
-    from hypothesis import strategies
-    from hypothesis import strategies as st
-    HAVE_HYPOTHESIS = True
-except ImportError:
-    HAVE_HYPOTHESIS = False
-
-    import functools
-    import inspect
-    import random
-    import zlib
-
-    class _Strategy:
-        def __init__(self, draw, desc: str):
-            self._draw = draw
-            self._desc = desc
-
-        def example(self, rnd: random.Random):
-            return self._draw(rnd)
-
-        def __repr__(self):
-            return self._desc
-
-    class _Data:
-        """st.data() handle: interactive draws inside the test body."""
-
-        def __init__(self, rnd: random.Random):
-            self._rnd = rnd
-
-        def draw(self, strategy, label=None):
-            return strategy.example(self._rnd)
-
-    class _DataStrategy(_Strategy):
-        def __init__(self):
-            super().__init__(lambda rnd: _Data(rnd), "data()")
-
-    class _Strategies:
-        @staticmethod
-        def integers(min_value=0, max_value=1 << 30):
-            return _Strategy(lambda rnd: rnd.randint(min_value, max_value),
-                             f"integers({min_value}, {max_value})")
-
-        @staticmethod
-        def floats(min_value=0.0, max_value=1.0, width=64, **_kw):
-            def draw(rnd):
-                v = rnd.uniform(min_value, max_value)
-                if width == 32:
-                    import struct
-                    v = struct.unpack("f", struct.pack("f", v))[0]
-                return v
-            return _Strategy(draw, f"floats({min_value}, {max_value})")
-
-        @staticmethod
-        def sampled_from(elements):
-            elems = list(elements)
-            return _Strategy(lambda rnd: elems[rnd.randrange(len(elems))],
-                             f"sampled_from({elems!r})")
-
-        @staticmethod
-        def lists(elements, min_size=0, max_size=None):
-            hi = max_size if max_size is not None else min_size + 10
-            return _Strategy(
-                lambda rnd: [elements.example(rnd)
-                             for _ in range(rnd.randint(min_size, hi))],
-                f"lists({elements!r}, {min_size}, {hi})")
-
-        @staticmethod
-        def data():
-            return _DataStrategy()
-
-    strategies = _Strategies()
-    st = strategies
-
-    class settings:
-        """Both a decorator (``@settings(...)``) and a plain container."""
-
-        def __init__(self, max_examples: int = 100, deadline=None, **_kw):
-            self.max_examples = max_examples
-            self.deadline = deadline
-
-        def __call__(self, fn):
-            fn._pc_settings = self
-            return fn
-
-    def given(*pos_strategies, **kw_strategies):
-        def decorate(fn):
-            sig = inspect.signature(fn)
-            names = list(sig.parameters)
-            bound = dict(kw_strategies)
-            # positional strategies bind to the rightmost parameters
-            for name, strat in zip(names[len(names) - len(pos_strategies):],
-                                   pos_strategies):
-                bound[name] = strat
-
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                cfg = getattr(wrapper, "_pc_settings", None)
-                n = cfg.max_examples if cfg is not None else 100
-                seed = zlib.crc32(
-                    f"{fn.__module__}.{fn.__qualname__}".encode())
-                rnd = random.Random(seed)
-                for i in range(n):
-                    drawn = {k: s.example(rnd) for k, s in bound.items()}
-                    try:
-                        fn(*args, **{**kwargs, **drawn})
-                    except Exception as e:
-                        raise AssertionError(
-                            f"falsifying example (iteration {i}): "
-                            f"{ {k: v for k, v in drawn.items()} }") from e
-
-            # hide strategy-bound params from pytest's fixture resolution
-            wrapper.__signature__ = sig.replace(parameters=[
-                p for name, p in sig.parameters.items() if name not in bound])
-            return wrapper
-
-        return decorate
-
-
-__all__ = ["given", "settings", "strategies", "st", "HAVE_HYPOTHESIS"]
+__all__ = ["given", "settings", "strategies", "st"]

@@ -3,35 +3,24 @@ tiny configs, covering every step kind and every §Perf knob.  (The full
 512-device production lowering is exercised by repro.launch.dryrun.)"""
 import dataclasses
 
-import jax
 import pytest
 
 from repro.configs import get_tiny_config
 from repro.configs.base import InputShape
+from repro.launch.mesh import auto_mesh
 from repro.launch.steps import lower_pair
 
 TRAIN = InputShape("t", 64, 4, "train")
 PREFILL = InputShape("p", 64, 4, "prefill")
 DECODE = InputShape("d", 64, 4, "decode")
 
-# MoE expert-parallel lowering resolves shard_map through the compat
-# shim (jax.shard_map where it exists, else the experimental entry
-# point with the check_rep/check_vma kwarg translated) — skip only when
-# the build has neither, so tier-1 stays green signal everywhere
-from repro.sharding import shard_map_available
-
-needs_shard_map = pytest.mark.skipif(
-    not shard_map_available(),
-    reason="this jax build has no shard_map entry point (MoE ep path)")
-
 
 def small_mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return auto_mesh((1, 1), ("data", "model"))
 
 
 @pytest.mark.parametrize("arch", ["granite-3-8b",
-                                  pytest.param("mixtral-8x7b",
-                                               marks=needs_shard_map),
+                                  "mixtral-8x7b",
                                   "mamba2-370m", "zamba2-1.2b",
                                   "whisper-tiny", "llama-3.2-vision-11b"])
 @pytest.mark.parametrize("shape", [TRAIN, PREFILL, DECODE],

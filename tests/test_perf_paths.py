@@ -11,7 +11,6 @@ from repro.configs import get_tiny_config
 from repro.models import forward, init_cache, init_params
 from repro.models.moe import moe_forward
 from repro.models.transformer import set_remat_policy
-from repro.sharding import shard_map_available
 
 
 @pytest.fixture(scope="module")
@@ -124,9 +123,6 @@ def test_contiguous_update_nonzero_start(dense_setup):
                                   np.asarray(cb["slot_pos"]))
 
 
-@pytest.mark.skipif(
-    not shard_map_available(),
-    reason="this jax build has no shard_map entry point (MoE ep path)")
 def test_moe_scatter_matches_psum():
     """psum_scatter MoE combine == full psum combine (on a real mesh)."""
     from jax.sharding import Mesh

@@ -3,19 +3,20 @@ logical_to_spec guards, the params-tree NamedSharding builder, the
 batch-axis divisibility guard, and the engine's token-exact
 column-parallel spec."""
 import jax
+import jax.numpy as jnp
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_tiny_config
+from repro.launch.mesh import auto_mesh
 from repro.models import init_params
 from repro.sharding import (ShardCtx, batch_axes, exact_col_spec,
                             head_axis, logical_to_spec, param_rules,
-                            param_sharding, resolve_shard_map,
-                            shape_tree, shard_map_available)
+                            param_sharding, shape_tree)
 
 
 def mesh_2x2():
-    return jax.make_mesh((2, 2), ("data", "model"))
+    return auto_mesh((2, 2), ("data", "model"))
 
 
 def sctx_2x2(**kw):
@@ -56,7 +57,7 @@ def test_logical_to_spec_drops_reused_axis():
 
 
 def test_logical_to_spec_multi_axis_tuple():
-    mesh = jax.make_mesh((2, 2, 1), ("pod", "data", "model"))
+    mesh = auto_mesh((2, 2, 1), ("pod", "data", "model"))
     rules = {"batch": ("pod", "data")}
     spec = logical_to_spec(("batch", "seq"), rules, mesh, (8, 4))
     assert spec == P(("pod", "data"), None)
@@ -105,7 +106,7 @@ def test_batch_axes_empty_dp_returns_none():
 
 
 def test_batch_axes_prefix_fallback():
-    mesh = jax.make_mesh((2, 2, 1), ("pod", "data", "model"))
+    mesh = auto_mesh((2, 2, 1), ("pod", "data", "model"))
     sctx = ShardCtx(mesh=mesh, dp=("pod", "data"))
     assert batch_axes(sctx, 4) == ("pod", "data")
     assert batch_axes(sctx, 2) == ("pod",)   # 2 % 4 != 0 -> prefix
@@ -149,19 +150,21 @@ def test_exact_col_spec_divisibility_guard():
         P(None, None)
 
 
-# ---------------- shard_map compat shim --------------------------------------
+# ---------------- shard_map on an engine-style mesh ---------------------------
 
 
 def test_shard_map_resolves_on_this_build():
-    assert shard_map_available()
-    fn = resolve_shard_map()
-    mesh = jax.make_mesh((2,), ("model",))
-    import jax.numpy as jnp
+    """``jax.shard_map`` over a 2-device ``Auto`` mesh: each device sees
+    its own shard, and a collective inside the body reduces across
+    them (the MoE ep path's in_specs/psum contract)."""
+    mesh = auto_mesh((2,), ("model",))
 
     def f(x):
-        return x * 2
+        return x * 2, jax.lax.psum(x.sum(keepdims=True), "model")
 
-    g = fn(f, mesh=mesh, in_specs=P("model"), out_specs=P("model"),
-           check_vma=False)
-    out = g(jnp.arange(4.0))
-    assert out.tolist() == [0.0, 2.0, 4.0, 6.0]
+    g = jax.shard_map(f, mesh=mesh, in_specs=P("model"),
+                      out_specs=(P("model"), P()), check_vma=False)
+    doubled, total = g(jnp.arange(4.0))
+    assert doubled.tolist() == [0.0, 2.0, 4.0, 6.0]
+    assert total.tolist() == [6.0]
+    assert doubled.sharding.spec == P("model")

@@ -14,6 +14,7 @@ import jax
 import numpy as np
 import pytest
 
+from repro.core import SeerRollout
 from repro.engine import (EngineSeq, Instance, StepFunctions,
                           build_token_tree, chain_tree)
 
@@ -237,6 +238,62 @@ def test_cross_tp_migration_token_exact(arch, tiny_params_cache):
     while not seq.finished:
         inst.run_step()
     assert seq.generated == oracle.generated
+
+
+@pytest.mark.parametrize("arch", ["granite-3-8b", "zamba2-1.2b"])
+def test_placed_instances_migrate_token_exact(arch, tiny_params_cache):
+    """Instances placed with ``devices=`` keep params and cache on their
+    own devices, and a request hopping device 1 -> device 2 -> a tp=2
+    mesh on devices 3-4 -> device 1 lands each import on the target's
+    devices and continues token-exact vs the default-device oracle."""
+    cfg, params = tiny_params_cache(arch)
+    steps = StepFunctions(cfg)
+    prompt = list(range(2, 16))
+    n_new = 16
+    kw = dict(max_slots=2, cache_len=128, gamma_max=0, prefill_chunk=8,
+              base_seed=7)
+    oracle_inst = Instance(cfg, params, steps, **kw)
+    oracle = _seq("ref", prompt, n_new, seed=1)
+    oracle_inst.admit(oracle)
+    while not oracle.finished:
+        oracle_inst.run_step()
+
+    devs = jax.devices()
+    hops = [(None, [devs[1]]), (None, [devs[2]]), (TP, devs[3:5]),
+            (None, [devs[1]])]
+    seq = _seq("r0", prompt, n_new, seed=1)
+    inst, slot = None, None
+    for hop, (tp, placed) in enumerate(hops):
+        nxt = Instance(cfg, params, steps, instance_id=f"hop{hop}", tp=tp,
+                       devices=placed, **kw)
+        for leaf in jax.tree.leaves((nxt.params, nxt.cache)):
+            assert leaf.sharding.device_set == set(placed)
+        if inst is None:
+            slot = nxt.admit(seq)
+        else:
+            blob = inst.release(slot, export=True).stamp_checksum()
+            slot = nxt.admit(seq, blob)
+            assert nxt.prefill_tokens == 0      # blob hit: no re-prefill
+        inst = nxt
+        for _ in range(4):
+            if not seq.finished:
+                inst.run_step()
+        for leaf in jax.tree.leaves(inst.cache):
+            assert leaf.sharding.device_set == set(placed)
+    while not seq.finished:
+        inst.run_step()
+    assert seq.generated == oracle.generated
+
+
+def test_instance_devices_must_match_tp(tiny_params_cache):
+    cfg, params = tiny_params_cache("granite-3-8b")
+    steps = StepFunctions(cfg)
+    with pytest.raises(ValueError, match="given 2 devices"):
+        Instance(cfg, params, steps, max_slots=1, cache_len=32,
+                 devices=jax.devices()[:2])
+    with pytest.raises(ValueError, match="need 4 devices, given 3"):
+        SeerRollout(cfg, params, steps=steps, n_instances=2, tp=2,
+                    max_slots=1, cache_len=32, devices=jax.devices()[:3])
 
 
 def test_tp_requires_enough_devices(tiny_params_cache):
