@@ -504,8 +504,9 @@ def _observability_checks(fresh: dict, base: dict, args) -> list:
     an untraced one (tokens, engine steps, host syncs), and attaching
     the tracer must not change the host-syncs-per-step ratio — every
     hook records host-side metadata the rollout already holds.  The
-    trace itself is a pure function of (seed, config): two traced runs
-    serialize identically and the Chrome export round-trips losslessly.
+    trace's ticks, names and args are a pure function of (seed,
+    config): two traced runs record them identically (their wall
+    seconds differ) and the Chrome export round-trips losslessly.
     Span conservation (phase spans tile each finished request's wall
     interval exactly) is what makes tail attribution trustworthy, and
     the seeded fault+overload run must actually produce a tail to
@@ -538,7 +539,8 @@ def _observability_checks(fresh: dict, base: dict, args) -> list:
         ("obs_trace_deterministic",
          fresh.get("trace_deterministic") is True
          and fresh.get("chrome_roundtrip") is True,
-         "repeat run event-identical and Chrome JSON round-trips: "
+         "repeat run identical in ticks, names and args, and Chrome "
+         "JSON round-trips: "
          f"{fresh.get('trace_deterministic')}, "
          f"{fresh.get('chrome_roundtrip')}"),
         ("obs_overload_attribution",

@@ -6,6 +6,10 @@ per-request phase timelines and prints the tail-attribution table:
 wall-time percentiles, per-phase totals, and the phase decomposition of
 the p99 / p999 / slowest-10% cohorts versus the full population.
 
+Seconds are the trace's own: an engine trace reports wall seconds (the
+JAX profiler's host clock, counted from the tracer's start), a
+simulator trace modeled seconds.
+
 Usage::
 
     PYTHONPATH=src python scripts/trace_report.py trace.json

@@ -44,6 +44,7 @@ from repro.core.sdmodel import ForwardCostModel, SDThroughputModel, TPU_V5E
 from repro.engine.engine import (BlobCorruptionError, EngineSeq, Instance,
                                  StepFunctions)
 from repro.engine.token_tree import TokenTree, build_token_tree
+from repro.obs.trace import NO_SPAN
 
 
 def _stat(default, doc: str):
@@ -316,12 +317,11 @@ class SeerRollout:
         self._stream_drained = False
         # -- observability ----------------------------------------------
         # optional repro.obs.trace.Tracer: all hooks are host-side
-        # metadata recorded at tick boundaries — tracing adds ZERO
-        # device reads, and a traced run is bit-identical (tokens,
-        # steps, host syncs) to an untraced one.  Settable between
-        # runs, like ``faults``.
+        # metadata recorded at tick boundaries or around the tick's
+        # host phases — tracing adds ZERO device reads, and a traced
+        # run is bit-identical (tokens, steps, host syncs) to an
+        # untraced one.  Settable between runs, like ``faults``.
         self.tracer = tracer
-        self._fwd = fwd              # modeled-clock source for the tracer
         self._stream_rec = None      # live TimelineRecorder (in-stream)
 
     # -- scheduling glue ---------------------------------------------------------
@@ -921,51 +921,53 @@ class SeerRollout:
         admitted finished.  With ``arrivals=None`` every branch below is
         a no-op and the run is bit-identical to the closed-loop path.
         """
-        t0 = time.monotonic()
-        stats = RolloutStats()
-        sched = Scheduler(list(groups), self.ctx, policy=self.policy,
-                          chunk_size=self.chunk_size,
-                          oracle_lengths=self.oracle_lengths,
-                          fetch_cost=(self._fetch_cost
-                                      if self.topology_aware else None),
-                          rank_mode=self.admission_rank,
-                          queue_cost_per_token=self._queue_cost_per_token,
-                          slo_deadline_s=slo_deadline_s)
-        all_groups = {g.group_id: g for g in groups}
-        self._stream_sched = sched
-        self._stream_stats = stats
-        self._stream_groups = all_groups
-        self._stream_drained = False
-        self._stuck_until = {}
-        self._watchdog = {}
-        self._cur_tick = 0
-        self._reqs = {r.req_id: r for g in groups for r in g.requests}
-        self._req_epoch = {rid: self._epoch for rid in self._reqs}
-        yielded: set = set()
-        for r in self._reqs.values():
-            r.t_submitted = t0
-
-        # observability: propagate the tracer (or clear a previous
-        # run's) through every collaborator and open the per-request
-        # timeline recorder.  All hooks downstream are guarded on the
-        # attribute being non-None, so the untraced path is untouched.
         tr = self.tracer
-        for inst in self.instances:
-            inst.tracer = tr
-        self.pool.tracer = tr
-        sched.tracer = tr
-        if self.faults is not None:
-            self.faults.tracer = tr
-        if arrivals is not None:
-            arrivals.tracer = tr
-        rec = None
-        if tr is not None:
-            from repro.obs.timeline import TimelineRecorder
-            rec = TimelineRecorder(tr)
-            for g in groups:
-                for r in g.requests:
-                    rec.on_submit(r.req_id, g.group_id, 0)
-        self._stream_rec = rec
+        with tr.phase("seer.iteration_open", "rollout") if tr is not None \
+                else NO_SPAN:
+            t0 = time.monotonic()
+            stats = RolloutStats()
+            sched = Scheduler(list(groups), self.ctx, policy=self.policy,
+                              chunk_size=self.chunk_size,
+                              oracle_lengths=self.oracle_lengths,
+                              fetch_cost=(self._fetch_cost
+                                          if self.topology_aware else None),
+                              rank_mode=self.admission_rank,
+                              queue_cost_per_token=self._queue_cost_per_token,
+                              slo_deadline_s=slo_deadline_s)
+            all_groups = {g.group_id: g for g in groups}
+            self._stream_sched = sched
+            self._stream_stats = stats
+            self._stream_groups = all_groups
+            self._stream_drained = False
+            self._stuck_until = {}
+            self._watchdog = {}
+            self._cur_tick = 0
+            self._reqs = {r.req_id: r for g in groups for r in g.requests}
+            self._req_epoch = {rid: self._epoch for rid in self._reqs}
+            yielded: set = set()
+            for r in self._reqs.values():
+                r.t_submitted = t0
+
+            # observability: propagate the tracer (or clear a previous
+            # run's) through every collaborator and open the per-request
+            # timeline recorder.  All hooks downstream are guarded on the
+            # attribute being non-None, so the untraced path is untouched.
+            for inst in self.instances:
+                inst.tracer = tr
+            self.pool.tracer = tr
+            sched.tracer = tr
+            if self.faults is not None:
+                self.faults.tracer = tr
+            if arrivals is not None:
+                arrivals.tracer = tr
+            rec = None
+            if tr is not None:
+                from repro.obs.timeline import TimelineRecorder
+                rec = TimelineRecorder(tr)
+                for g in groups:
+                    for r in g.requests:
+                        rec.on_submit(r.req_id, g.group_id, 0)
+            self._stream_rec = rec
 
         try:
             yield from self._stream_loop(sched, stats, all_groups,
@@ -1043,7 +1045,6 @@ class SeerRollout:
             any_active = False
             any_blocked = False
             tickets = []
-            tick_dt = 0.0     # modeled seconds this tick covers
             for inst in self.instances:
                 if not inst.alive:
                     continue
@@ -1072,33 +1073,16 @@ class SeerRollout:
                             self._crash_instance(inst, sched, stats)
                     continue
                 self._watchdog.pop(inst.instance_id, None)
-                ticket, drafts, cost_in = None, {}, None
+                ticket, drafts = None, {}
                 if inst.active_slots() or inst.pending_takeovers():
-                    drafts = self._collect_drafts(inst)
-                    if tr is not None:
-                        # modeled-clock inputs, captured BEFORE dispatch
-                        # consumes the prefill queues (host-side reads
-                        # only — the tracer never touches the device)
-                        dec = inst.decode_slots()
-                        cost_in = (
-                            len(dec),
-                            sum(min(inst.slots[i].next_pos,
-                                    inst.cache_len) for i in dec),
-                            max((len(drafts.get(i, [])) for i in dec),
-                                default=0),
-                            sum(min(len(inst.slots[i].prefill_queue),
-                                    inst.prefill_chunk)
-                                for i in inst.prefilling_slots()))
+                    with tr.phase("seer.drafts", inst.instance_id) \
+                            if tr is not None else NO_SPAN:
+                        drafts = self._collect_drafts(inst)
                     ticket = inst.dispatch_step(drafts)
                 if ticket is None:
                     continue
                 any_active = True
                 tickets.append((inst, drafts, ticket))
-                if tr is not None and cost_in is not None:
-                    n_dec, ctx_sum, gamma, pf_tokens = cost_in
-                    mean_ctx = ctx_sum / max(n_dec, 1)
-                    tick_dt = max(tick_dt, self._fwd.mixed_step_time(
-                        max(n_dec, 1), 1 + gamma, pf_tokens, mean_ctx))
                 if self._epoch:
                     # tail-packing currency: a step whose batch mixes
                     # inject epochs is running next-iteration rows in
@@ -1121,10 +1105,12 @@ class SeerRollout:
             # Same-instance arrivals share one batched KV import
             # (flushed by the instance at its next dispatch).
             admitted = 0
-            for r, iid in sched.plan_admissions(
-                    [v for v in self._views() if v.free_slots > 0]):
-                self._admit(sched, r, iid, stats)
-                admitted += 1
+            with tr.phase("seer.admit", "scheduler") if tr is not None \
+                    else NO_SPAN:
+                for r, iid in sched.plan_admissions(
+                        [v for v in self._views() if v.free_slots > 0]):
+                    self._admit(sched, r, iid, stats)
+                    admitted += 1
 
             # 3) flush the deferred KV exports (chunks released last
             # tick): the batched gather is enqueued behind the step it
@@ -1137,118 +1123,135 @@ class SeerRollout:
             for inst in self.instances:
                 if not inst.alive or self._is_stuck(inst):
                     continue
-                freed += self._flush_releases(inst, sched)
+                with tr.phase("seer.export", inst.instance_id) \
+                        if tr is not None else NO_SPAN:
+                    freed += self._flush_releases(inst, sched)
             if freed:
-                for r, iid in sched.plan_admissions(
-                        [v for v in self._views() if v.free_slots > 0]):
-                    self._admit(sched, r, iid, stats)
-                    admitted += 1
+                with tr.phase("seer.admit", "scheduler") \
+                        if tr is not None else NO_SPAN:
+                    for r, iid in sched.plan_admissions(
+                            [v for v in self._views()
+                             if v.free_slots > 0]):
+                        self._admit(sched, r, iid, stats)
+                        admitted += 1
 
             # 4) commit results and run chunk/finish bookkeeping;
             # finished groups are buffered and yielded only after every
             # ticket committed (no step in flight at any yield point)
             finished_groups: List[Group] = []
             for inst, drafts, ticket in tickets:
-                out = inst.commit_step(ticket)
-                stats.steps += 1
-                for slot, (new_toks, _lps, n_acc) in out.items():
-                    seq = inst.slots[slot]
-                    r = self._reqs[seq.req_id]
-                    d = drafts.get(slot, [])
-                    n_draft = len(d)
-                    stats.tokens += len(new_toks)
-                    # staleness ledger: note only genuinely-new tokens.
-                    # Replayed/re-decoded tokens from crash recovery are
-                    # already recorded under the param versions they
-                    # were originally sampled at; the ledger catches up
-                    # to len(seq.generated) and then records normally
-                    # (at the crossover commit, only the truly-new
-                    # suffix of new_toks is noted).
-                    fresh = len(seq.generated) - r.version_tokens_recorded()
-                    if fresh > 0:
-                        r.note_version_tokens(self.param_version,
-                                              min(fresh, len(new_toks)))
-                    if seq.reval_queue:
-                        # prefix revalidation: the drafts came from the
-                        # old-params generation, not the CST.  Excluded
-                        # from the β profile (they measure old-policy
-                        # agreement, not CST quality).  Consume the
-                        # re-accepted prefix; any divergence — a
-                        # rejected draft, or a bonus token that departs
-                        # from the old trajectory — drops the rest.
-                        stats.reval_tokens += n_draft
-                        stats.reval_accepted += n_acc
-                        q = seq.reval_queue
-                        if seq.finished or n_acc < n_draft \
-                                or len(q) == n_draft:
-                            seq.reval_queue = []
-                        elif new_toks and q[n_draft] == new_toks[-1]:
-                            del q[:n_draft + 1]
+                with tr.phase("seer.commit", inst.instance_id,
+                              rows=len(ticket.sample_slots)) \
+                        if tr is not None else NO_SPAN:
+                    out = inst.commit_step(ticket)
+                    stats.steps += 1
+                    cst_updates = []
+                    for slot, (new_toks, _lps, n_acc) in out.items():
+                        seq = inst.slots[slot]
+                        r = self._reqs[seq.req_id]
+                        d = drafts.get(slot, [])
+                        n_draft = len(d)
+                        stats.tokens += len(new_toks)
+                        # staleness ledger: note only genuinely-new tokens.
+                        # Replayed/re-decoded tokens from crash recovery are
+                        # already recorded under the param versions they
+                        # were originally sampled at; the ledger catches up
+                        # to len(seq.generated) and then records normally
+                        # (at the crossover commit, only the truly-new
+                        # suffix of new_toks is noted).
+                        fresh = len(seq.generated) \
+                            - r.version_tokens_recorded()
+                        if fresh > 0:
+                            r.note_version_tokens(self.param_version,
+                                                  min(fresh, len(new_toks)))
+                        if seq.reval_queue:
+                            # prefix revalidation: the drafts came from the
+                            # old-params generation, not the CST.  Excluded
+                            # from the β profile (they measure old-policy
+                            # agreement, not CST quality).  Consume the
+                            # re-accepted prefix; any divergence — a
+                            # rejected draft, or a bonus token that departs
+                            # from the old trajectory — drops the rest.
+                            stats.reval_tokens += n_draft
+                            stats.reval_accepted += n_acc
+                            q = seq.reval_queue
+                            if seq.finished or n_acc < n_draft \
+                                    or len(q) == n_draft:
+                                seq.reval_queue = []
+                            elif new_toks and q[n_draft] == new_toks[-1]:
+                                del q[:n_draft + 1]
+                            else:
+                                seq.reval_queue = []
                         else:
-                            seq.reval_queue = []
-                    else:
-                        stats.drafted += n_draft
-                        stats.accepted += n_acc
-                        if n_draft and isinstance(d, TokenTree):
-                            # per-branch β: attribute the accepted chain
-                            # to the beam rank that drafted it (trunk
-                            # misses count against the trunk)
-                            self.ctx.record_tree_verification(
-                                d.winner_rank(new_toks[:n_acc]),
-                                d.max_depth, n_acc, n_ranks=len(d.paths))
-                        elif n_draft:
-                            self.ctx.record_verification(n_draft, n_acc)
-                    if new_toks:
-                        # stable speculator id: python str hash is
-                        # randomized per process (PYTHONHASHSEED), which
-                        # made DGDS ids — and draft paths — nondeterministic
-                        self.server.update_cst(
-                            r.group_id,
-                            zlib.crc32(r.req_id.encode()) & 0x7FFFFFFF,
-                            len(seq.generated) - len(new_toks), new_toks)
-                # 3) chunk / finish bookkeeping
-                for slot in list(inst.active_slots()):
-                    seq = inst.slots[slot]
-                    r = self._reqs[seq.req_id]
-                    _, _, _, chunk = self._placements[r.req_id]
-                    consumed = len(seq.generated) - len(r.generated)
-                    if seq.finished:
-                        self._release(r, stats, export=False)
-                        self.pool.drop(r.req_id)
-                        r.finish(time.monotonic())
-                        sched.on_finished(r)
-                        if rec is not None:
-                            rec.on_finish(r.req_id, tick)
-                        if feed is not None:
-                            feed.note_request_finished(
-                                r.req_id, r.group_id, tick,
-                                len(r.generated))
-                        g = all_groups.get(r.group_id)
-                        if g is not None and g.all_finished \
-                                and r.group_id not in yielded:
-                            yielded.add(r.group_id)
-                            finished_groups.append(g)
-                    elif consumed >= chunk:
-                        remaining = r.max_new_tokens - len(seq.generated)
-                        if self.final_chunk_inplace and \
-                                0 < remaining <= self.chunk_size:
-                            # eviction-aware export: the request fits its
-                            # final chunk budget — renew in place, skip
-                            # the pool round-trip (the blob would be
-                            # fetched once and dropped)
-                            self._sync_back(r, seq)
-                            self._placements[r.req_id] = \
-                                (inst, slot, seq, remaining)
-                            stats.chunks += 1
-                            stats.inplace_renewals += 1
-                            r.chunks_run += 1
+                            stats.drafted += n_draft
+                            stats.accepted += n_acc
+                            if n_draft and isinstance(d, TokenTree):
+                                # per-branch β: attribute the accepted chain
+                                # to the beam rank that drafted it (trunk
+                                # misses count against the trunk)
+                                self.ctx.record_tree_verification(
+                                    d.winner_rank(new_toks[:n_acc]),
+                                    d.max_depth, n_acc, n_ranks=len(d.paths))
+                            elif n_draft:
+                                self.ctx.record_verification(n_draft, n_acc)
+                        if new_toks:
+                            # stable speculator id: python str hash is
+                            # randomized per process (PYTHONHASHSEED),
+                            # which made DGDS ids — and draft paths —
+                            # nondeterministic
+                            cst_updates.append((
+                                r.group_id,
+                                zlib.crc32(r.req_id.encode()) & 0x7FFFFFFF,
+                                len(seq.generated) - len(new_toks), new_toks))
+                    # the rows' CST updates, in row order: nothing between
+                    # here and the next tick's drafts reads the CST
+                    with tr.phase("seer.cst_update", inst.instance_id) \
+                            if tr is not None else NO_SPAN:
+                        for args in cst_updates:
+                            self.server.update_cst(*args)
+                    # 3) chunk / finish bookkeeping
+                    for slot in list(inst.active_slots()):
+                        seq = inst.slots[slot]
+                        r = self._reqs[seq.req_id]
+                        _, _, _, chunk = self._placements[r.req_id]
+                        consumed = len(seq.generated) - len(r.generated)
+                        if seq.finished:
+                            self._release(r, stats, export=False)
+                            self.pool.drop(r.req_id)
+                            r.finish(time.monotonic())
+                            sched.on_finished(r)
                             if rec is not None:
-                                rec.on_renew(r.req_id, tick)
-                        elif inst.migration_mode == "batched":
-                            self._begin_release(r, stats)
-                        else:
-                            self._release(r, stats, export=True)
-                            sched.requeue(r)
+                                rec.on_finish(r.req_id, tick)
+                            if feed is not None:
+                                feed.note_request_finished(
+                                    r.req_id, r.group_id, tick,
+                                    len(r.generated))
+                            g = all_groups.get(r.group_id)
+                            if g is not None and g.all_finished \
+                                    and r.group_id not in yielded:
+                                yielded.add(r.group_id)
+                                finished_groups.append(g)
+                        elif consumed >= chunk:
+                            remaining = r.max_new_tokens - len(seq.generated)
+                            if self.final_chunk_inplace and \
+                                    0 < remaining <= self.chunk_size:
+                                # eviction-aware export: the request fits its
+                                # final chunk budget — renew in place, skip
+                                # the pool round-trip (the blob would be
+                                # fetched once and dropped)
+                                self._sync_back(r, seq)
+                                self._placements[r.req_id] = \
+                                    (inst, slot, seq, remaining)
+                                stats.chunks += 1
+                                stats.inplace_renewals += 1
+                                r.chunks_run += 1
+                                if rec is not None:
+                                    rec.on_renew(r.req_id, tick)
+                            elif inst.migration_mode == "batched":
+                                self._begin_release(r, stats)
+                            else:
+                                self._release(r, stats, export=True)
+                                sched.requeue(r)
 
             # 5) stream finished groups (every ticket has committed —
             # no step in flight, so consumers may inject/refresh here)
@@ -1293,9 +1296,8 @@ class SeerRollout:
 
             # end of tick: classify every open request into exactly one
             # phase (span conservation holds by construction — one
-            # segment per live request per tick) and advance the
-            # modeled clock by the tick's widest dispatched step (an
-            # idle tick costs one nominal decode step).
+            # segment per live request per tick) and stamp the tick's
+            # end on the tracer's clock.
             if tr is not None:
                 if rec is not None:
                     placed = {}
@@ -1308,17 +1310,18 @@ class SeerRollout:
                         else:
                             placed[rid] = "prefill"
                     rec.end_tick(tick, placed)
-                tr.advance_tick(tick_dt if tick_dt > 0.0
-                                else self._fwd.step_time(1, 1, 0.0))
+                tr.end_tick()
 
-        stats.wall_seconds = time.monotonic() - t0
-        stats.offer_delay_max = max(sched.offer_delays, default=0.0)
-        if rec is not None:
-            rec.finalize()
-        result = RolloutResult(
-            groups=list(all_groups.values()), stats=stats,
-            ctx_stats=self.ctx.stats(), pool_stats=self.pool.stats(),
-            dgds_stats=self.server.stats())
+        with tr.phase("seer.iteration_close", "rollout") \
+                if tr is not None else NO_SPAN:
+            stats.wall_seconds = time.monotonic() - t0
+            stats.offer_delay_max = max(sched.offer_delays, default=0.0)
+            if rec is not None:
+                rec.finalize()
+            result = RolloutResult(
+                groups=list(all_groups.values()), stats=stats,
+                ctx_stats=self.ctx.stats(), pool_stats=self.pool.stats(),
+                dgds_stats=self.server.stats())
         for gid, g in all_groups.items():
             # groups that were already finished at submit time (or empty)
             # never pass through the commit loop — flush them here

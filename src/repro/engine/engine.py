@@ -139,6 +139,7 @@ from repro.engine.sampling import (draft_acceptance, position_keys,
                                    tree_acceptance)
 from repro.engine.token_tree import TokenTree, bucket_pow2, chain_tree
 from repro.models import build_cross_cache, forward, init_cache
+from repro.obs.trace import NO_SPAN
 from repro.sharding import ShardCtx
 
 _INT32_MAX = np.iinfo(np.int32).max
@@ -207,6 +208,7 @@ class StepFunctions:
             self.invocations_by_kind[kind] = \
                 self.invocations_by_kind.get(kind, 0) + 1
             return fn(*args)
+        wrapper.lower = fn.lower      # the jitted program, uncounted
         return wrapper
 
     def step(self, T: int, sctx: Optional[ShardCtx] = None):
@@ -294,47 +296,51 @@ class StepFunctions:
                 cfg, params, tokens, positions, cache, token_mask=mask,
                 sctx=sctx)
             logits = logits.astype(jnp.float32)
-            sampled = sample_tokens(logits, keys, temps, sample_rows)
-            lp = token_logprobs_at(logits, sampled)
-            n_acc = draft_acceptance(sampled, tokens, anchor, n_drafts)
-            # on-device commit: the accepted chain of row i covers
-            # positions [pos(anchor), pos(anchor)+n_acc]; invalidate every
-            # cache slot beyond it (rejected drafts)
-            anchor_pos = jnp.take_along_axis(
-                positions, anchor[:, None], axis=1)[:, 0]
-            committed_end = jnp.where(
-                sample_rows, anchor_pos + n_acc + 1, _INT32_MAX)
-            if "slot_pos" in new_cache:
-                new_cache["slot_pos"] = jnp.where(
-                    new_cache["slot_pos"] >= committed_end[:, None], -1,
-                    new_cache["slot_pos"])
-            if has_rec and T > 1:
-                # SSM states advanced through *rejected* draft tokens
-                # cannot be invalidated by slot masking — replay the
-                # accepted prefix from the pre-step recurrent state as a
-                # masked second pass in the same jit (beyond-paper:
-                # spec-decode on SSM/hybrid archs; see DESIGN.md).
-                # Prefill rows keep their full mask: every chunk token is
-                # "accepted" and the replay recomputes their state
-                # identically.
-                cols = jnp.arange(T)[None, :]
-                acc_mask = mask & jnp.where(
-                    sample_rows[:, None],
-                    cols <= (anchor + n_acc)[:, None], True)
+            with jax.named_scope("sample"):
+                sampled = sample_tokens(logits, keys, temps, sample_rows)
+                lp = token_logprobs_at(logits, sampled)
+            with jax.named_scope("accept"):
+                n_acc = draft_acceptance(sampled, tokens, anchor, n_drafts)
+                # on-device commit: the accepted chain of row i covers
+                # positions [pos(anchor), pos(anchor)+n_acc]; invalidate every
+                # cache slot beyond it (rejected drafts)
+                anchor_pos = jnp.take_along_axis(
+                    positions, anchor[:, None], axis=1)[:, 0]
+                committed_end = jnp.where(
+                    sample_rows, anchor_pos + n_acc + 1, _INT32_MAX)
+                if "slot_pos" in new_cache:
+                    new_cache["slot_pos"] = jnp.where(
+                        new_cache["slot_pos"] >= committed_end[:, None], -1,
+                        new_cache["slot_pos"])
+                if has_rec and T > 1:
+                    # SSM states advanced through *rejected* draft tokens
+                    # cannot be invalidated by slot masking — replay the
+                    # accepted prefix from the pre-step recurrent state as a
+                    # masked second pass in the same jit (beyond-paper:
+                    # spec-decode on SSM/hybrid archs; see DESIGN.md).
+                    # Prefill rows keep their full mask: every chunk token is
+                    # "accepted" and the replay recomputes their state
+                    # identically.
+                    cols = jnp.arange(T)[None, :]
+                    acc_mask = mask & jnp.where(
+                        sample_rows[:, None],
+                        cols <= (anchor + n_acc)[:, None], True)
 
-                def replay(nc):
-                    c2 = dict(nc)
-                    c2.update(pre_rec)
-                    _, c3, _ = forward(cfg, params, tokens, positions,
-                                       c2, token_mask=acc_mask,
-                                       sctx=sctx)
-                    return c3
+                    def replay(nc):
+                        c2 = dict(nc)
+                        c2.update(pre_rec)
+                        _, c3, _ = forward(cfg, params, tokens, positions,
+                                           c2, token_mask=acc_mask,
+                                           sctx=sctx)
+                        return c3
 
-                new_cache = jax.lax.cond(
-                    jnp.any(acc_mask != mask), replay, lambda nc: nc,
-                    new_cache)
+                    new_cache = jax.lax.cond(
+                        jnp.any(acc_mask != mask), replay, lambda nc: nc,
+                        new_cache)
             return sampled, lp, n_acc, new_cache
 
+        # the program's name in HLO and on the profiler's device plane
+        raw.__name__ = f"seer_step_t{T}"
         fn = jax.jit(raw, donate_argnums=(1,))
         counted = self._counted(fn, f"fused:{T}")
         self._step_cache[key] = counted
@@ -380,71 +386,74 @@ class StepFunctions:
                 cfg, params, tokens, positions, cache, token_mask=mask,
                 slot_index=slot_index, within_mask=within, sctx=sctx)
             logits = logits.astype(jnp.float32)
-            sampled = sample_tokens(logits, keys, temps, sample_rows)
-            lp = token_logprobs_at(logits, sampled)
-            n_acc, path_col, acc = tree_acceptance(
-                sampled, tokens, parent, depth, within, mask, anchor)
-            n_acc = jnp.where(sample_rows, n_acc, 0)
-            # path-major relayout: column d of the output holds the
-            # sample/logprob at the accepted path's depth-d node, so the
-            # host commit is identical to the linear path at offset 0
-            out_sampled = jnp.take_along_axis(sampled, path_col, axis=1)
-            out_lp = jnp.take_along_axis(lp, path_col, axis=1)
-            anchor_pos = jnp.take_along_axis(
-                positions, anchor[:, None], axis=1)[:, 0]
-            if "slot_pos" in new_cache:
-                S = new_cache["slot_pos"].shape[1]
-                bidx = jnp.arange(B)[:, None]
-                # 1) invalidate every tree-node slot (this step's
-                # writes); 2) re-commit the winning branch into the
-                # canonical slots (slot == position, mod ring) so the
-                # cache looks exactly as if the accepted chain had been
-                # decoded linearly
-                node_slots = jnp.where((depth > 0) & mask, slot_index, S)
-                sp = new_cache["slot_pos"].at[bidx, node_slots].set(
-                    -1, mode="drop")
-                dcols = jnp.arange(T, dtype=jnp.int32)[None, :]
-                dvalid = (dcols >= 1) & (dcols <= n_acc[:, None]) \
-                    & sample_rows[:, None]
-                src = jnp.where(
-                    dvalid,
-                    jnp.take_along_axis(slot_index, path_col, axis=1), S)
-                dst_pos = anchor_pos[:, None] + dcols
-                dst = jnp.where(dvalid, dst_pos % S if ring else dst_pos,
-                                S)
-                new_cache["slot_pos"] = sp.at[bidx, dst].set(
-                    dst_pos, mode="drop")
-                src_c = jnp.clip(src, 0, S - 1)
-                for kk in ("k", "v"):
-                    kv = new_cache[kk]            # (L, B, S, H, D)
-                    vals = jnp.take_along_axis(
-                        kv, src_c[None, :, :, None, None], axis=2)
-                    new_cache[kk] = kv.at[:, bidx, dst].set(
-                        vals, mode="drop")
-            if has_rec and T > 1:
-                # recurrent state advanced through rejected tree nodes:
-                # replay the accepted path (anchor + accepted chain, in
-                # column order = topological order) from the pre-step
-                # state; prefill rows keep their full mask
-                cols = jnp.arange(T)[None, :]
-                keep = mask & jnp.where(
-                    sample_rows[:, None],
-                    (cols <= anchor[:, None]) | acc, True)
+            with jax.named_scope("sample"):
+                sampled = sample_tokens(logits, keys, temps, sample_rows)
+                lp = token_logprobs_at(logits, sampled)
+            with jax.named_scope("accept"):
+                n_acc, path_col, acc = tree_acceptance(
+                    sampled, tokens, parent, depth, within, mask, anchor)
+                n_acc = jnp.where(sample_rows, n_acc, 0)
+                # path-major relayout: column d of the output holds the
+                # sample/logprob at the accepted path's depth-d node, so the
+                # host commit is identical to the linear path at offset 0
+                out_sampled = jnp.take_along_axis(sampled, path_col, axis=1)
+                out_lp = jnp.take_along_axis(lp, path_col, axis=1)
+                anchor_pos = jnp.take_along_axis(
+                    positions, anchor[:, None], axis=1)[:, 0]
+                if "slot_pos" in new_cache:
+                    S = new_cache["slot_pos"].shape[1]
+                    bidx = jnp.arange(B)[:, None]
+                    # 1) invalidate every tree-node slot (this step's
+                    # writes); 2) re-commit the winning branch into the
+                    # canonical slots (slot == position, mod ring) so the
+                    # cache looks exactly as if the accepted chain had been
+                    # decoded linearly
+                    node_slots = jnp.where((depth > 0) & mask, slot_index, S)
+                    sp = new_cache["slot_pos"].at[bidx, node_slots].set(
+                        -1, mode="drop")
+                    dcols = jnp.arange(T, dtype=jnp.int32)[None, :]
+                    dvalid = (dcols >= 1) & (dcols <= n_acc[:, None]) \
+                        & sample_rows[:, None]
+                    src = jnp.where(
+                        dvalid,
+                        jnp.take_along_axis(slot_index, path_col, axis=1), S)
+                    dst_pos = anchor_pos[:, None] + dcols
+                    dst = jnp.where(dvalid, dst_pos % S if ring else dst_pos,
+                                    S)
+                    new_cache["slot_pos"] = sp.at[bidx, dst].set(
+                        dst_pos, mode="drop")
+                    src_c = jnp.clip(src, 0, S - 1)
+                    for kk in ("k", "v"):
+                        kv = new_cache[kk]            # (L, B, S, H, D)
+                        vals = jnp.take_along_axis(
+                            kv, src_c[None, :, :, None, None], axis=2)
+                        new_cache[kk] = kv.at[:, bidx, dst].set(
+                            vals, mode="drop")
+                if has_rec and T > 1:
+                    # recurrent state advanced through rejected tree nodes:
+                    # replay the accepted path (anchor + accepted chain, in
+                    # column order = topological order) from the pre-step
+                    # state; prefill rows keep their full mask
+                    cols = jnp.arange(T)[None, :]
+                    keep = mask & jnp.where(
+                        sample_rows[:, None],
+                        (cols <= anchor[:, None]) | acc, True)
 
-                def replay(nc):
-                    c2 = dict(nc)
-                    c2.update(pre_rec)
-                    _, c3, _ = forward(cfg, params, tokens, positions,
-                                       c2, token_mask=keep,
-                                       slot_index=slot_index,
-                                       within_mask=within, sctx=sctx)
-                    return c3
+                    def replay(nc):
+                        c2 = dict(nc)
+                        c2.update(pre_rec)
+                        _, c3, _ = forward(cfg, params, tokens, positions,
+                                           c2, token_mask=keep,
+                                           slot_index=slot_index,
+                                           within_mask=within, sctx=sctx)
+                        return c3
 
-                new_cache = jax.lax.cond(
-                    jnp.any(keep != mask), replay, lambda nc: nc,
-                    new_cache)
+                    new_cache = jax.lax.cond(
+                        jnp.any(keep != mask), replay, lambda nc: nc,
+                        new_cache)
             return out_sampled, out_lp, n_acc, new_cache
 
+        raw.__name__ = f"seer_tree_step_t{T}"
         fn = jax.jit(raw, donate_argnums=(1,))
         counted = self._counted(fn, f"tree:{T}")
         self._step_cache[key] = counted
@@ -497,7 +506,7 @@ class StepFunctions:
             jit_kwargs["out_shardings"] = NamedSharding(sctx.mesh, P())
 
         @partial(jax.jit, **jit_kwargs)
-        def fn(cache, slots):
+        def seer_export(cache, slots):
             gathered = {}
             for k, v in cache.items():
                 sax = _slot_slice(k)
@@ -516,8 +525,8 @@ class StepFunctions:
                 out.append(leaves)
             return out
 
-        self._step_cache[key] = fn
-        return fn
+        self._step_cache[key] = seer_export
+        return seer_export
 
     def import_batch(self, sctx: Optional[ShardCtx] = None):
         """Jitted multi-slot KV scatter: ``(cache, slots(n,), [blob leaf
@@ -539,7 +548,7 @@ class StepFunctions:
         if key in self._step_cache:
             return self._step_cache[key]
 
-        def raw(cache, slots, blobs):
+        def seer_import(cache, slots, blobs):
             new = dict(cache)
             for k in cache:
                 sax = _slot_slice(k)
@@ -558,7 +567,7 @@ class StepFunctions:
                     jnp.moveaxis(src, 0, sax).astype(cache[k].dtype))
             return new
 
-        fn = jax.jit(raw, donate_argnums=(0,))
+        fn = jax.jit(seer_import, donate_argnums=(0,))
         self._step_cache[key] = fn
         return fn
 
@@ -915,6 +924,10 @@ class Instance:
         # wasted rows = rows carrying neither decode nor prefill work
         self.row_slots_total = 0
         self.row_slots_active = 0
+        # column occupancy: every forward scores max_slots x T columns;
+        # active = the step's mask (decode, draft and prefill tokens)
+        self.cols_total = 0
+        self.cols_active = 0
         self.prefill_rows_packed = 0   # chunk-rows of prefill work issued
         self.tail_fused_rows = 0       # tail chunks fused with 1st decode
         # tree-speculation accounting: steps that verified >= 1 tree
@@ -1354,13 +1367,16 @@ class Instance:
                 (k, v.shape[_pos_axis(k)]) for k, v in blob.arrays.items()
                 if _pos_axis(k) is not None))
             by_extent.setdefault(ext, []).append((slot, blob))
-        for group in by_extent.values():
-            slots = jnp.asarray([s for s, _ in group], jnp.int32)
-            blobs = [self._localize_blob_arrays(b.arrays)
-                     for _, b in group]
-            self.cache = self.steps.import_batch(self._sctx)(
-                self.cache, slots, blobs)
-            self.steps.count_migration(f"import:{len(group)}")
+        tr = self.tracer
+        with tr.phase("seer.import", self.instance_id) if tr is not None \
+                else NO_SPAN:
+            for group in by_extent.values():
+                slots = jnp.asarray([s for s, _ in group], jnp.int32)
+                blobs = [self._localize_blob_arrays(b.arrays)
+                         for _, b in group]
+                self.cache = self.steps.import_batch(self._sctx)(
+                    self.cache, slots, blobs)
+                self.steps.count_migration(f"import:{len(group)}")
         self.migration_host_seconds += time.perf_counter() - t0
 
     def _clear_slot_cache(self, slot: int) -> None:
@@ -1403,6 +1419,8 @@ class Instance:
             self.prefill_tokens += len(chunk)
             self.row_slots_total += B
             self.row_slots_active += 1
+            self.cols_total += mask.size
+            self.cols_active += len(chunk)
             self.prefill_rows_packed += 1
 
     # -- the mixed prefill / decode / verify step ---------------------------------
@@ -1489,11 +1507,16 @@ class Instance:
         on several instances before committing any, overlapping host
         work with device compute.
         """
+        tr = self.tracer
+        with (tr.phase("seer.dispatch", self.instance_id)
+              if tr is not None else NO_SPAN) as span:
+            return self._dispatch_step(drafts or {}, span)
+
+    def _dispatch_step(self, drafts, span):
         if self._inflight is not None:
             raise RuntimeError("dispatch_step() with a ticket in flight")
         if not self.alive:
             raise RuntimeError("dispatch_step() on a crashed instance")
-        drafts = drafts or {}
         if self.prefill_mode == "sync":
             return _SyncTicket(self._run_step_sync(drafts))
         if self._takeovers:
@@ -1514,11 +1537,9 @@ class Instance:
         plan = self._prefill_plan()
         if not decode and not plan:
             return None
-        if self.tracer is not None:
-            self.tracer.instant(
-                "step_dispatch", "instance", self.instance_id,
-                decode_rows=len(decode), prefill_rows=len(plan),
-                prefill_tokens=sum(plan.values()))
+        if span is not None:
+            span.args.update(decode_rows=len(decode), prefill_rows=len(plan),
+                             prefill_tokens=sum(plan.values()))
         if self.spec_mode == "tree":
             return self._dispatch_tree(decode, plan, drafts)
         gamma = max((len(drafts.get(i, [])) for i in decode), default=0)
@@ -1596,6 +1617,8 @@ class Instance:
             jnp.asarray(anchor), jnp.asarray(n_drafts))
         self.row_slots_total += B
         self.row_slots_active += len(decode) + len(plan)
+        self.cols_total += mask.size
+        self.cols_active += int(mask.sum())
         self.prefill_rows_packed += len(plan)
         self.tail_fused_rows += len(fused)
 
@@ -1641,6 +1664,8 @@ class Instance:
             jnp.asarray(bt.depth))
         self.row_slots_total += self.max_slots
         self.row_slots_active += len(decode) + len(plan)
+        self.cols_total += bt.mask.size
+        self.cols_active += int(bt.mask.sum())
         self.prefill_rows_packed += len(plan)
         self.tail_fused_rows += len(bt.fused)
         self.tree_steps += 1 if bt.n_tree_nodes else 0
@@ -1786,15 +1811,11 @@ class Instance:
             raise RuntimeError("commit_step(): ticket is not the "
                                "instance's in-flight step")
         self._inflight = None
-        sampled, lps, n_acc = jax.device_get(
-            (ticket.sampled, ticket.lps, ticket.n_acc))
+        with self.tracer.phase("seer.commit_wait", self.instance_id) \
+                if self.tracer is not None else NO_SPAN:
+            sampled, lps, n_acc = jax.device_get(
+                (ticket.sampled, ticket.lps, ticket.n_acc))
         self.steps.host_syncs += 1
-        if self.tracer is not None:
-            # stamped right after the step's one explicit device_get —
-            # the tracer itself reads only the already-fetched host ints
-            self.tracer.instant(
-                "step_commit", "instance", self.instance_id,
-                rows=len(ticket.sample_slots))
         out = {}
         for i in ticket.sample_slots:
             seq = self.slots[i]
@@ -1923,6 +1944,8 @@ class Instance:
         self.steps.host_syncs += 2   # full sample + logprob blocks
         self.row_slots_total += B
         self.row_slots_active += len(decode) + len(plan)
+        self.cols_total += mask.size
+        self.cols_active += int(mask.sum())
         self.prefill_rows_packed += len(plan)
 
         # consume queued prefill that this step just wrote to the cache
@@ -2000,6 +2023,8 @@ class Instance:
         self.steps.host_syncs += 2   # full sample + logprob blocks
         self.row_slots_total += B
         self.row_slots_active += len(decode) + len(plan)
+        self.cols_total += bt.mask.size
+        self.cols_active += int(bt.mask.sum())
         self.prefill_rows_packed += len(plan)
         self.tail_fused_rows += len(bt.fused)
         self.tree_steps += 1 if bt.n_tree_nodes else 0

@@ -186,6 +186,7 @@ def _moe_body(xf, router, wg, wu, wd, sg, su, sd, *, cfg, e0_fn, E_loc, C,
     return y, aux
 
 
+@jax.named_scope("mlp")
 def moe_forward(x, p, cfg, sctx: Optional[ShardCtx]):
     """x: (B,S,d) -> (y, aux)."""
     if sctx is None:

@@ -238,6 +238,7 @@ def _project_qkv(p, xn, cfg, positions=None):
     return q, k, v
 
 
+@jax.named_scope("attention")
 def _self_attn(p, x, cfg, positions, slots, ck, cv, slot_pos, token_mask,
                causal=True, sctx=None, attn_allowed=None):
     """Returns (x_out, new_ck, new_cv).  ck/cv None => no-cache (training).
@@ -330,6 +331,7 @@ def _self_attn(p, x, cfg, positions, slots, ck, cv, slot_pos, token_mask,
     return x + o, nk, nv
 
 
+@jax.named_scope("attention")
 def _cross_attn(p, x, cfg, kv_or_embeds, from_cache: bool, sctx=None):
     """Cross attention to static memory (image/audio embeddings)."""
     exact = sctx is not None and sctx.exact
@@ -355,6 +357,7 @@ def _cross_attn(p, x, cfg, kv_or_embeds, from_cache: bool, sctx=None):
     return x + lin(o, p["wo"]), k, v
 
 
+@jax.named_scope("mlp")
 def _mlp(p, x, cfg, sctx=None):
     exact = sctx is not None and sctx.exact
     xn = rms_norm(x, p["ln"], cfg.rms_eps)

@@ -20,9 +20,10 @@ The :class:`TimelineRecorder` classifies every live request into
 exactly one phase per stream-loop tick (``end_tick``), which makes the
 **span-conservation invariant** hold by construction: each finished
 request's phase durations tile its wall interval exactly, in ticks and
-— through the tracer's monotone tick->seconds table — in modeled
-seconds.  ``tail_attribution`` then decomposes p99/p999 and the
-last-10% tail window into these phases; the report is the flight
+— through the tracer's monotone tick->seconds table — in seconds (wall
+seconds in the engine tier, modeled seconds in the simulator tier).
+``tail_attribution`` then decomposes p99/p999 and the last-10% tail
+window into these phases; the report is the flight
 recorder's answer to "*why* is the tail long", not just "how long".
 """
 from __future__ import annotations
@@ -44,7 +45,7 @@ class RequestTimeline:
     """One request's reconstructed timeline.
 
     ``segments`` are ``(phase, tick0, tick1)`` half-open tick spans;
-    ``spans_s`` the matching ``(phase, t0, t1)`` modeled-second spans.
+    ``spans_s`` the matching ``(phase, t0, t1)`` spans in seconds.
     ``end_tick`` is exclusive (the tick after the finishing tick);
     ``None`` while the request is still open (or was shed).
     """
@@ -79,7 +80,7 @@ class RequestTimeline:
 
     def conserved(self, rel: float = 1e-9) -> bool:
         """Phase durations tile the wall interval: contiguous spans,
-        summing to the wall in modeled seconds (and, when the segments
+        summing to the wall in seconds (and, when the segments
         carry real ticks, exactly in ticks)."""
         if not self.spans_s:
             return not self.finished

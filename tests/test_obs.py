@@ -1,18 +1,26 @@
 """Flight-recorder observability: tracing must be pure observation.
 
-Tracer level: the two deterministic clocks (ticks + modeled seconds),
-Chrome trace-event round-trip, and the cross-tier event schema.
+Tracer level: ticks and the wall clock shared with the JAX profiler
+(phase spans land in a profiler trace under their own names, at the
+Tracer's stamps), Chrome trace-event round-trip, and the cross-tier
+event schema.
 
 Timeline level: span-conservation on synthetic timelines (gaps and
 short sums are *detected*, not papered over) and the tail-attribution
 report's shape.
 
 Rollout level: a traced run is bit-identical to an untraced one
-(tokens, engine steps, host syncs), the trace itself is a pure function
-of (seed, config), every finished request's phase spans tile its wall
-interval in ticks and modeled seconds, and a crash schedule shows up as
+(tokens, engine steps, host syncs), an untraced run creates no profiler
+annotation, the trace's ticks, names and args are a pure function of
+(seed, config), every finished request's phase spans tile its wall
+interval in ticks and wall seconds, the tick's host phases nest as the
+idle attribution reads them, and a crash schedule shows up as
 ``recovery`` spans with the recovery-path kind stamped on the instant —
 all without tripping the device->host transfer guard.
+
+Engine level: the fused step is named ``seer_step_t{T}`` in HLO with
+its model parts in named scopes, and the column counters count the
+step's mask.
 
 Stats level: the ``RolloutStats`` counter audit, mechanized — every
 field documented and read somewhere outside its definition — and the
@@ -20,8 +28,10 @@ unified ``snapshot()`` surface benches consume."""
 import dataclasses
 import json
 import os
+import time
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 from repro.core.faults import FaultEvent, FaultInjector
@@ -30,7 +40,7 @@ from repro.core.rollout import RolloutStats, SeerRollout
 from repro.engine import EngineSeq, Instance, StepFunctions
 from repro.obs import (PHASES, RequestTimeline, Tracer, format_attribution,
                        tail_attribution, timelines_from_events)
-from repro.obs.trace import CATEGORIES, SCHEMA_KEYS, schema_keys
+from repro.obs.trace import CATEGORIES, SCHEMA_KEYS, TICK_EVENT, schema_keys
 
 
 @pytest.fixture(scope="module")
@@ -84,28 +94,100 @@ def test_tracer_clock_and_event_resolution():
     tr = Tracer()
     tr.begin_tick(0)
     tr.instant("a", "instance", "inst0", x=1)
-    tr.advance_tick(0.5)
+    tr.end_tick()
     tr.begin_tick(1)
-    tr.advance_tick(0.25)
+    with tr.phase("seer.admit", "scheduler", n=2) as ev:
+        ev.args["admitted"] = 1
+    tr.end_tick()
     tr.span("decode", "request", "r0", 0, 2)
     tr.span("sim", "request", "r1", 0, 1, t0=3.0, t1=4.5)
-    assert tr.tick_time(0) == 0.0
-    assert tr.tick_time(1) == 0.5
-    assert tr.tick_time(2) == 0.75
-    assert tr.tick_time(99) == 0.75          # clamped, never IndexError
+    t = [tr.tick_time(k) for k in range(3)]
+    assert 0.0 <= t[0] <= t[1] <= t[2] <= tr.now()
+    assert tr.tick_time(99) == t[2]          # clamped, never IndexError
     evs = tr.events()
-    assert [sorted(e) for e in evs] == [sorted(SCHEMA_KEYS)] * 3
-    assert evs[0]["t0"] == 0.0 and evs[0]["args"] == {"x": 1}
-    assert evs[1]["t0"] == 0.0 and evs[1]["t1"] == 0.75   # tick-table
-    assert evs[2]["t0"] == 3.0 and evs[2]["t1"] == 4.5    # explicit floats
+    assert [sorted(e) for e in evs] == [sorted(SCHEMA_KEYS)] * 4
+    assert t[0] <= evs[0]["t0"] <= t[1] and evs[0]["args"] == {"x": 1}
+    assert evs[1]["name"] == "seer.admit" and evs[1]["cat"] == "phase"
+    assert evs[1]["tick0"] == evs[1]["tick1"] == 1
+    assert t[1] <= evs[1]["t0"] <= evs[1]["t1"] <= t[2]
+    assert evs[1]["args"] == {"n": 2, "admitted": 1}
+    assert evs[2]["t0"] == t[0] and evs[2]["t1"] == t[2]  # tick table
+    assert evs[3]["t0"] == 3.0 and evs[3]["t1"] == 4.5    # explicit floats
     assert all(e["cat"] in CATEGORIES for e in evs)
+    tr.begin_tick(0)                         # a new stream's table
+    assert tr.tick_time(1) == tr.tick_time(0) > t[2]
+    tr.end_tick()
+
+
+def _profiled(tmp_path, body):
+    """Run ``body`` under a CPU profiler trace; its host-plane events as
+    (name, absolute start ns, duration ns, stats)."""
+    from jax.profiler import ProfileData
+    with jax.profiler.trace(str(tmp_path)):
+        body()
+    path = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    pd = ProfileData.from_file(str(path))
+    planes = {p.name: p for p in pd.planes}
+    # event starts are offsets from the session's start on the
+    # profiler's host clock (CLOCK_REALTIME)
+    base = dict(planes["Task Environment"].stats)["profile_start_time"]
+    return [(ev.name, base + ev.start_ns, ev.duration_ns, dict(ev.stats))
+            for line in planes["/host:CPU"].lines for ev in line.events]
+
+
+def test_phase_spans_land_in_the_profiler_trace(tmp_path):
+    """Each phase span and tick is a profiler annotation of the same
+    name, and the Tracer's stamps are the profiler's: every span starts
+    within 1 ms of its profiler event, with one clock and no offset."""
+    tr = Tracer()
+
+    def body():
+        for k in range(3):
+            tr.begin_tick(k)
+            with tr.phase("seer.drafts", "inst0"):
+                time.sleep(0.002)
+            with tr.phase("seer.commit", "inst0"):
+                with tr.phase("seer.commit_wait", "inst0"):
+                    jnp.ones((32, 32)).sum().block_until_ready()
+            tr.end_tick()
+
+    host = _profiled(tmp_path, body)
+    mine = [e for e in tr.events() if e["cat"] == "phase"]
+    assert len(mine) == 9
+    for name in ("seer.drafts", "seer.commit", "seer.commit_wait"):
+        theirs = sorted(s for n, s, _, _ in host if n == name)
+        ours = [tr.origin_ns + e["t0"] * 1e9 for e in mine
+                if e["name"] == name]
+        assert len(theirs) == len(ours) == 3
+        for a, b in zip(ours, theirs):
+            assert abs(a - b) < 1e6, (name, a - b)
+    ticks = [st for n, _, _, st in host if n == TICK_EVENT]
+    assert sorted(st["step_num"] for st in ticks) == [0, 1, 2]
+
+
+def test_untraced_run_creates_no_annotation(tiny, monkeypatch):
+    """With no tracer attached no span object and no profiler
+    annotation is created: a run with both annotation types made to
+    raise serves the same tokens as one without."""
+    cfg, params, steps = tiny
+    res_ref, _, _ = _run(cfg, params, steps)
+
+    def refuse(*a, **k):
+        raise AssertionError("annotation created with no tracer")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", refuse)
+    res, _, _ = _run(cfg, params, steps)
+    assert res.responses() == res_ref.responses()
+    with pytest.raises(AssertionError):
+        _run(cfg, params, steps, tracer=Tracer())
 
 
 def test_chrome_roundtrip_is_lossless():
     tr = Tracer()
     tr.begin_tick(0)
     tr.instant("fault_crash", "fault", "inst1", lose_pool=True, count=1)
-    tr.advance_tick(1.5)
+    tr.end_tick()
     tr.span("queue", "request", "r0", 0, 1, tenant="a", group="g0")
     evs = tr.events()
     doc = json.loads(json.dumps(tr.to_chrome()))   # through real JSON
@@ -173,11 +255,18 @@ def test_trace_off_bit_identity(traced_run):
     assert syncs_on == syncs_off
 
 
+def _untimed(events):
+    """What of a trace is a pure function of (seed, config): everything
+    but the engine tier's wall seconds."""
+    return [{k: v for k, v in e.items() if k not in ("t0", "t1")}
+            for e in events]
+
+
 def test_trace_is_deterministic(tiny, traced_run):
     cfg, params, steps = tiny
     tr2 = Tracer()
     _run(cfg, params, steps, tracer=tr2)
-    assert tr2.events() == traced_run["tracer"].events()
+    assert _untimed(tr2.events()) == _untimed(traced_run["tracer"].events())
 
 
 def test_engine_chrome_roundtrip(traced_run):
@@ -188,7 +277,7 @@ def test_engine_chrome_roundtrip(traced_run):
 
 def test_span_conservation_on_engine_trace(traced_run):
     """Every finished request's phase spans tile its wall interval —
-    exactly in ticks, and to fp tolerance in modeled seconds."""
+    exactly in ticks, and to fp tolerance in wall seconds."""
     evs = traced_run["tracer"].events()
     tls = timelines_from_events(evs)
     done = [tl for tl in tls.values() if tl.finished]
@@ -202,6 +291,33 @@ def test_span_conservation_on_engine_trace(traced_run):
     assert rep["phase_totals_s"].get("decode", 0.0) > 0.0
 
 
+def test_engine_phases_nest_as_the_attribution_reads_them(traced_run):
+    """The tick's host phases: each opens and closes within one tick,
+    on the wall clock; every commit wait lies inside a commit of its
+    instance, and no two phases of one tick overlap otherwise."""
+    evs = [e for e in traced_run["tracer"].events() if e["cat"] == "phase"]
+    names = {e["name"] for e in evs}
+    assert {"seer.iteration_open", "seer.iteration_close", "seer.drafts",
+            "seer.dispatch", "seer.admit", "seer.export", "seer.import",
+            "seer.commit", "seer.commit_wait",
+            "seer.cst_update"} >= names >= {
+        "seer.iteration_open", "seer.iteration_close", "seer.dispatch",
+        "seer.admit", "seer.commit", "seer.commit_wait",
+        "seer.cst_update"}
+    assert all(e["t0"] <= e["t1"] for e in evs)
+    commits = [e for e in evs if e["name"] == "seer.commit"]
+    waits = [e for e in evs if e["name"] == "seer.commit_wait"]
+    assert len(waits) == len(commits) == traced_run["on"][1]
+    for w in waits:
+        assert any(c["track"] == w["track"] and c["t0"] <= w["t0"]
+                   and w["t1"] <= c["t1"] for c in commits)
+    nested = {"seer.commit_wait", "seer.cst_update", "seer.import"}
+    top = sorted((e["t0"], e["t1"]) for e in evs
+                 if e["name"] not in nested
+                 and not e["name"].startswith("seer.iteration"))
+    assert all(a[1] <= b[0] for a, b in zip(top, top[1:]))
+
+
 def test_engine_schema_is_the_shared_schema(traced_run):
     evs = traced_run["tracer"].events()
     assert schema_keys(evs) == sorted(SCHEMA_KEYS)
@@ -209,9 +325,9 @@ def test_engine_schema_is_the_shared_schema(traced_run):
 
 
 def test_tracer_hooks_pass_transfer_guard(tiny):
-    """The dispatch/commit instants record host ints already in hand;
-    with the guard disallowing implicit device->host transfers, a traced
-    step loop must behave exactly like the untraced one."""
+    """The dispatch and commit-wait spans record host ints already in
+    hand; with the guard disallowing implicit device->host transfers, a
+    traced step loop must behave exactly like the untraced one."""
     cfg, params, steps = tiny
     inst = Instance(cfg, params, steps, max_slots=2, cache_len=64,
                     gamma_max=0, prefill_chunk=8, base_seed=7)
@@ -226,7 +342,47 @@ def test_tracer_hooks_pass_transfer_guard(tiny):
         assert steps.host_syncs - syncs0 <= 1
     assert len(s.generated) == 8
     names = {e["name"] for e in inst.tracer.events()}
-    assert names == {"step_dispatch", "step_commit"}
+    assert names == {"seer.dispatch", "seer.commit_wait"}
+
+
+def test_column_counters_count_the_step_mask(tiny):
+    """``cols_active`` is the sum of each step's mask, ``cols_total``
+    max_slots x T: a 6-token prompt prefills its first 5 tokens at T=8
+    with the pending 6th fused (6 of 16 columns), then decodes at T=1
+    (1 of 2)."""
+    cfg, params, steps = tiny
+    inst = Instance(cfg, params, steps, max_slots=2, cache_len=64,
+                    gamma_max=0, prefill_chunk=8, base_seed=7)
+    s = EngineSeq("r0", "g0", [2, 3, 4, 5, 6, 7], seed=3, max_new_tokens=4)
+    inst.admit(s)
+    inst.run_step()
+    assert (inst.cols_active, inst.cols_total) == (6, 16)
+    inst.run_step()
+    assert (inst.cols_active, inst.cols_total) == (7, 18)
+    assert inst.row_slots_active == 2 and inst.row_slots_total == 4
+
+
+def test_fused_step_is_a_named_program_with_scoped_parts(tiny):
+    """The fused step lowers to a module named ``seer_step_t{T}`` whose
+    HLO metadata puts the model's attention and MLP, sampling and
+    acceptance under their named scopes."""
+    from repro.engine.sampling import position_keys
+    cfg, params, steps = tiny
+    inst = Instance(cfg, params, steps, max_slots=2, cache_len=64,
+                    gamma_max=0, prefill_chunk=8, base_seed=7)
+    B, T = 2, 4
+    z = jnp.zeros((B, T), jnp.int32)
+    zb = jnp.zeros((B,), jnp.int32)
+    lowered = steps.fused_step(T).lower(
+        params, inst.cache, z, z, jnp.zeros((B, T), bool),
+        position_keys(inst.base_key, zb, z), jnp.zeros((B,), jnp.float32),
+        jnp.zeros((B,), bool), zb, zb)
+    assert lowered.as_text().startswith(f"module @jit_seer_step_t{T} ")
+    hlo = lowered.compile().as_text()
+    assert hlo.startswith(f"HloModule jit_seer_step_t{T},")
+    for scope in ("attention", "mlp", "sample", "accept"):
+        assert f"jit(seer_step_t{T})/" in hlo
+        assert f"/{scope}/" in hlo, scope
 
 
 def test_crash_schedule_records_recovery_spans(tiny):
