@@ -1607,12 +1607,12 @@ class Instance:
                 anchor[i] = n
                 anchors[i] = n
 
-        keys = position_keys(self.base_key, jnp.asarray(seeds),
-                             jnp.asarray(positions))
+        positions_d = jnp.asarray(positions)
+        keys = position_keys(self.base_key, jnp.asarray(seeds), positions_d)
         fn = self.steps.fused_step(T, self._sctx)
         sampled, lps, n_acc, self.cache = fn(
             self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(positions), jnp.asarray(mask), keys,
+            positions_d, jnp.asarray(mask), keys,
             jnp.asarray(temps), jnp.asarray(sample_rows),
             jnp.asarray(anchor), jnp.asarray(n_drafts))
         self.row_slots_total += B
@@ -1652,12 +1652,13 @@ class Instance:
         compiled step shapes stay bounded.
         """
         bt = self._build_tree_batch(decode, plan, drafts)
+        positions_d = jnp.asarray(bt.positions)
         keys = position_keys(self.base_key, jnp.asarray(bt.seeds),
-                             jnp.asarray(bt.positions))
+                             positions_d)
         fn = self.steps.fused_tree_step(bt.T, self._sctx)
         sampled, lps, n_acc, self.cache = fn(
             self.params, self.cache, jnp.asarray(bt.tokens),
-            jnp.asarray(bt.positions), jnp.asarray(bt.slot_index),
+            positions_d, jnp.asarray(bt.slot_index),
             jnp.asarray(bt.mask), jnp.asarray(bt.within), keys,
             jnp.asarray(bt.temps), jnp.asarray(bt.sample_rows),
             jnp.asarray(bt.anchor), jnp.asarray(bt.parent),
@@ -1929,15 +1930,15 @@ class Instance:
             positions[i, :n] = seq.prefill_pos + np.arange(n)
             mask[i, :n] = True
 
-        keys = position_keys(self.base_key, jnp.asarray(seeds),
-                             jnp.asarray(positions))
+        positions_d = jnp.asarray(positions)
+        keys = position_keys(self.base_key, jnp.asarray(seeds), positions_d)
         fn = self.steps.step(T, self._sctx)
         has_ssm = "ssm" in self.cache
         pre_ssm = (self.cache["ssm"], self.cache["conv"]) \
             if (has_ssm and gamma > 0) else None
         sampled, lps, self.cache = fn(
             self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(positions), jnp.asarray(mask), keys,
+            positions_d, jnp.asarray(mask), keys,
             jnp.asarray(temps), jnp.asarray(sample_rows))
         sampled = np.asarray(sampled)
         lps = np.asarray(lps)
@@ -1986,7 +1987,7 @@ class Instance:
                 self.cache["ssm"], self.cache["conv"] = pre_ssm
                 _, _, self.cache = fn(
                     self.params, self.cache, jnp.asarray(tokens),
-                    jnp.asarray(positions), jnp.asarray(accepted_mask), keys,
+                    positions_d, jnp.asarray(accepted_mask), keys,
                     jnp.asarray(temps), jnp.asarray(sample_rows))
         self.steps_run += 1
         return out
@@ -2010,12 +2011,13 @@ class Instance:
             return {}
         bt = self._build_tree_batch(decode, plan, drafts)
         B, T = self.max_slots, bt.T
+        positions_d = jnp.asarray(bt.positions)
         keys = position_keys(self.base_key, jnp.asarray(bt.seeds),
-                             jnp.asarray(bt.positions))
+                             positions_d)
         fn = self.steps.tree_step(T, self._sctx)
         sampled_d, lps_d, self.cache = fn(
             self.params, self.cache, jnp.asarray(bt.tokens),
-            jnp.asarray(bt.positions), jnp.asarray(bt.slot_index),
+            positions_d, jnp.asarray(bt.slot_index),
             jnp.asarray(bt.mask), jnp.asarray(bt.within), keys,
             jnp.asarray(bt.temps), jnp.asarray(bt.sample_rows))
         sampled = np.asarray(sampled_d)

@@ -8,18 +8,54 @@ the on-policy guarantee Seer's synchronous RL setting requires (§3.4).
 """
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 
 
-def position_keys(base_key: jax.Array, seeds: jax.Array,
-                  positions: jax.Array) -> jax.Array:
-    """seeds: (B,), positions: (B,T) -> uint32 keys (B,T,2)."""
+#: ``position_keys`` makes keys for at least this many positions a row
+#: and keeps the first T: every step width up to it shares one compiled
+#: key program.  Lowering threefry's unrolled rounds costs about a second
+#: of host time per program on a TPU host, at every process start.
+KEY_COLUMNS = 64
+
+
+@jax.jit
+def _keys(base_key: jax.Array, seeds: jax.Array,
+          positions: jax.Array) -> jax.Array:
     def one(seed, pos_row):
         k = jax.random.fold_in(base_key, seed)
         return jax.vmap(lambda p: jax.random.key_data(
             jax.random.fold_in(k, p)))(pos_row)
     return jax.vmap(one)(seeds, positions)
+
+
+@partial(jax.jit, static_argnums=1)
+def _pad_columns(x: jax.Array, width: int) -> jax.Array:
+    return jnp.pad(x, ((0, 0), (0, width - x.shape[1])))
+
+
+@partial(jax.jit, static_argnums=1)
+def _first_columns(x: jax.Array, n: int) -> jax.Array:
+    return x[:, :n]
+
+
+def position_keys(base_key: jax.Array, seeds: jax.Array,
+                  positions: jax.Array) -> jax.Array:
+    """seeds: (B,), positions: (B,T) -> uint32 keys (B,T,2).
+
+    Compiled launches only (pad, keys, slice), so nothing is traced on
+    the host once a width has run; run eagerly, the nested vmaps would
+    re-trace ``fold_in`` at every step."""
+    T = positions.shape[1]
+    width = KEY_COLUMNS
+    while width < T:
+        width <<= 1
+    if width == T:
+        return _keys(base_key, seeds, positions)
+    return _first_columns(
+        _keys(base_key, seeds, _pad_columns(positions, width)), T)
 
 
 def sample_tokens(logits: jax.Array, keys: jax.Array,
