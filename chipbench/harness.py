@@ -15,17 +15,20 @@ logit lies below the reference's best decides ``correct``.
 ``readings`` keys:
   setup_s, window_s            host seconds: process start to window
                                start, and the window itself
+  instances                    one-chip instances, one per chip
   tokens                       output tokens committed in the window
-  engine_steps                 fused steps the instances ran
+  engine_steps                 fused steps the instances ran, together
   row_slots_active/_total      rows carrying work / rows computed
   drafted, accepted            speculative draft tokens and acceptances
+  chunks, migrations           chunks completed, and those re-admitted on
+                               another instance
   latencies                    per request: t_finished - iteration start
   iterations                   per iteration: makespan and sorted finish
                                latencies
   model_flops                  forward FLOPs of the window's requests
-  peak_flops                   the chip's published bf16 peak
+  peak_flops                   the chips' published bf16 peak, summed
   memory_peak_bytes, hbm_bytes peak_bytes_in_use after the window, and
-                               the device's bytes_limit
+                               the bytes_limit, of the fullest chip
   trace                        (traced runs) busy_s, window_s,
                                device_ops, idle_gaps; else None
 """
@@ -56,6 +59,8 @@ class NoChip(RuntimeError):
 
 @dataclass
 class Cell:
+    """A cell of ``chips`` chips runs one one-chip instance on each, all
+    behind one scheduler and one KV pool."""
     name: str
     conf: dict              # configs/<config>.json
     mix: dict               # traffic/<traffic>.json
@@ -63,7 +68,10 @@ class Cell:
 
     @property
     def groups(self) -> int:
-        return min(self.mix["max_groups"], self.conf["serving"]["groups"])
+        """Groups per iteration: each instance's share, as many times as
+        there are instances."""
+        return self.chips * min(self.mix["max_groups"],
+                                self.conf["serving"]["groups"])
 
     @property
     def content_seed(self) -> int:
@@ -73,9 +81,12 @@ class Cell:
 
 
 def cell(name: str) -> Cell:
+    """The cell, refused before any set-up where its model cannot be
+    counted (``flops.check``)."""
     w = cells.workload(name)
-    return Cell(name, cells.load_config(w["config"]),
-                cells.load_traffic(w["traffic"]), w["chips"])
+    conf = cells.load_config(w["config"])
+    flops.check(conf)
+    return Cell(name, conf, cells.load_traffic(w["traffic"]), w["chips"])
 
 
 def devices(chips: int, require_chip: bool = True):
@@ -97,19 +108,23 @@ def load_kind(kind: str):
     return importlib.import_module(f"chipbench.kinds.{kind}")
 
 
-def build(c: Cell, seed: int):
+def build(c: Cell, seed: int, devs=None):
     """Weights from the mix's content seed, made on the device, and the
-    rollout."""
+    rollout: one instance on each of the cell's chips (``devs``, default
+    JAX's devices; a device repeats where there are fewer)."""
     import jax
 
     from repro.core import SeerRollout
     from repro.launch.serve import init_params_on_device
+    devs = devs or jax.devices()
     cfg = cells.model_config(c.conf)
     params = init_params_on_device(cfg, c.content_seed % (1 << 32))
     jax.block_until_ready(params)
     s = c.conf["serving"]
     r = c.mix["rollout"]
-    ro = SeerRollout(cfg, params, n_instances=1, max_slots=s["max_slots"],
+    ro = SeerRollout(cfg, params, n_instances=c.chips,
+                     devices=[devs[i % len(devs)] for i in range(c.chips)],
+                     max_slots=s["max_slots"],
                      cache_len=s["cache_len"], chunk_size=r["chunk_size"],
                      policy=r["policy"], spec_decode=r["spec_decode"],
                      base_seed=seed % (1 << 31))
@@ -207,7 +222,7 @@ def measure(c: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     # set-up reads all of them back
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
-    cfg, params, ro = build(c, seed)
+    cfg, params, ro = build(c, seed, devs)
     log("weights made on the device")
     vocab = cfg.vocab_size
     kind = load_kind(c.mix["kind"])
@@ -242,17 +257,20 @@ def measure(c: Cell, seed: int, seconds: float, trace: bool, t_start: float,
         f"{readings['engine_steps']} steps, {readings['window_s']:.3f} s, "
         f"{clock.compiles} programs compiled inside it")
 
-    dev = devs[0]
-    mem = dev.memory_stats() or {}
+    readings["instances"] = len(ro.instances)
+    # the fullest of the cell's chips
+    used = {inst.device for inst in ro.instances}
+    dev, mem = max(((d, d.memory_stats() or {}) for d in used),
+                   key=lambda dm: dm[1].get("peak_bytes_in_use", 0))
     p = peaks(dev.device_kind) if require_chip else None
     readings["memory_peak_bytes"] = mem.get("peak_bytes_in_use")
     # the device's own allocator limit where it reports one, else the
     # published size
     readings["hbm_bytes"] = mem.get("bytes_limit") or (p.hbm_bytes if p
                                                        else None)
-    readings["peak_flops"] = p.bf16_flops if p else None
+    readings["peak_flops"] = len(used) * p.bf16_flops if p else None
     readings["model_flops"] = flops.flops_for_requests(
-        c.conf["model"], [(len(r.prompt), len(r.generated)) for r in served])
+        c.conf, [(len(r.prompt), len(r.generated)) for r in served])
     readings["trace"] = None
     if trace:
         readings["trace"] = reduce_trace()

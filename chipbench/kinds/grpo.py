@@ -50,14 +50,14 @@ def window(ro, cell, seed: int, vocab: int, deadline: float, readings: dict,
     """Iterations until ``deadline``; fills ``readings`` and returns every
     request served."""
     readings.update(latencies=[], iterations=[], tokens=0, drafted=0,
-                    accepted=0)
+                    accepted=0, chunks=0, migrations=0)
     served, last, k = [], 0.0, 0
     while k == 0 or deadline - time.monotonic() >= last:
         it = traffic_gen.grpo_iteration(cell.mix, seed, k, cell.groups,
                                         vocab=vocab)
-        steps0 = sum(inst.steps_run for inst in ro.instances)
+        steps0 = [inst.steps_run for inst in ro.instances]
         reqs, stats, t0, t1 = run_iteration(ro, it)
-        steps = sum(inst.steps_run for inst in ro.instances) - steps0
+        per = [inst.steps_run - s for inst, s in zip(ro.instances, steps0)]
         last, k = t1 - t0, k + 1
         lat = sorted(r.t_finished - t0 for r in reqs if r.finished)
         readings["iterations"].append({"makespan": last, "finish": lat})
@@ -65,9 +65,12 @@ def window(ro, cell, seed: int, vocab: int, deadline: float, readings: dict,
         readings["tokens"] += stats.tokens
         readings["drafted"] += stats.drafted
         readings["accepted"] += stats.accepted
+        readings["chunks"] += stats.chunks
+        readings["migrations"] += stats.migrations
         served += reqs
         p90 = lat[math.ceil(0.9 * len(lat)) - 1] if lat else float("nan")
         log(f"iteration {k - 1}: {len(lat)} requests, {stats.tokens} "
-            f"tokens, {steps} steps, {stats.accepted}/{stats.drafted} "
-            f"drafts accepted, {last:.3f} s, p90 {p90:.3f} s")
+            f"tokens, {sum(per)} steps {per}, {stats.accepted}/{stats.drafted} "
+            f"drafts accepted, {stats.migrations}/{stats.chunks} chunks "
+            f"migrated, {last:.3f} s, p90 {p90:.3f} s")
     return served

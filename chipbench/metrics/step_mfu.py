@@ -1,6 +1,6 @@
 """Model FLOPs of the window's requests (every prompt and output
-position once, at its context) per window second, over the chip's
-bf16 peak, in %."""
+position once, at its context) per window second, over the bf16 peak
+of the cell's chips together, in %."""
 
 
 def read(r):

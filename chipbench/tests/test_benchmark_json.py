@@ -49,3 +49,21 @@ def test_metrics_have_readers_and_move_an_end_to_end_metric():
     for m in B["per_layer"]:
         assert m["moves"] in e2e
         assert set(m["workloads"]) <= {w["name"] for w in B["workloads"]}
+
+
+def test_at_most_half_the_cells_ask_for_four_chips():
+    four = sum(w["chips"] == 4 for w in B["workloads"])
+    assert four <= max(1, len(B["workloads"]) // 2)
+
+
+def test_each_pair_of_config_and_traffic_is_one_cell():
+    pairs = [(w["config"], w["traffic"]) for w in B["workloads"]]
+    assert len(set(pairs)) == len(pairs)
+
+
+def test_every_cell_loads_and_serves_whole_groups_on_each_chip():
+    from chipbench import harness
+    for w in B["workloads"]:
+        c = harness.cell(w["name"])
+        assert c.chips == w["chips"]
+        assert c.groups % c.chips == 0 and c.groups >= c.chips

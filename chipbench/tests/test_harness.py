@@ -3,12 +3,16 @@ chip: healthy, it comes out correct; with a token altered where the
 engine produces it, or with a step that leaves the cache as it was, it
 does not; and with the float8 control in the program's place it does not
 either."""
+import dataclasses
 import os
+import re
 import shutil
 import subprocess
 import sys
 import time
 from pathlib import Path
+
+import pytest
 
 from chipbench import harness
 from chipbench.tests import tiny
@@ -68,6 +72,68 @@ def test_a_step_that_leaves_the_cache_as_it_was_is_not_correct(
 
     monkeypatch.setattr(StepFunctions, "fused_step", unchanged)
     line = run(tiny.cell(), 2 ** 31 + 14)
+    assert not line["correct"]
+    assert line["checks"]["max_logit_gap"]["value"] > 0.05
+
+
+def run_instances(monkeypatch, seed):
+    """A traced run of the tiny two-instance cell; its line, the readings
+    its metrics were read from, and its log."""
+    seen, logs = [], []
+    load = harness.load_reader
+
+    def recording(name):
+        read = load(name)
+
+        def reader(r):
+            seen.append(r)
+            return read(r)
+        return reader
+
+    monkeypatch.setattr(harness, "load_reader", recording)
+    line = harness.measure(tiny.instances_cell(2), seed, 0.5, True,
+                           time.monotonic(), require_chip=False,
+                           log=logs.append)
+    return line, seen[0], logs
+
+
+def test_two_instances_are_correct_and_count_steps_per_instance(
+        monkeypatch):
+    line, r, logs = run_instances(monkeypatch, 2 ** 31 + 21)
+    assert line["correct"], line["checks"]
+    assert line["failed"] == 0 and line["attempted"] >= 16
+    assert r["instances"] == 2
+    m = line["metrics"]
+    assert m["step_ms"]["value"] == pytest.approx(
+        1e3 * r["window_s"] / (r["engine_steps"] / 2))
+    assert m["migration_share"]["value"] > 0
+    assert m["migration_share"]["value"] == pytest.approx(
+        100.0 * r["migrations"] / r["chunks"])
+    # both instances ran steps in every iteration
+    per = [re.search(r"steps \[(\d+), (\d+)\]", s) for s in logs]
+    per = [tuple(map(int, g.groups())) for g in per if g]
+    assert per and all(a > 0 and b > 0 for a, b in per)
+
+
+def test_a_migration_that_carries_no_cache_is_not_correct(monkeypatch):
+    # the fault: a chunk re-admitted on another instance gets its blob
+    # with every key and value zeroed, as if the exchange between chips
+    # were left out
+    import jax.numpy as jnp
+    from repro.core.rollout import SeerRollout
+    fetch = SeerRollout._pool_fetch
+
+    def left_out(self, r, inst, stats):
+        blob = fetch(self, r, inst, stats)
+        if blob is None or r.instance_id in (None, inst.instance_id):
+            return blob
+        return dataclasses.replace(blob, arrays={
+            k: jnp.zeros_like(a) if jnp.issubdtype(a.dtype, jnp.floating)
+            else a for k, a in blob.arrays.items()})
+
+    monkeypatch.setattr(SeerRollout, "_pool_fetch", left_out)
+    line, r, _ = run_instances(monkeypatch, 2 ** 31 + 22)
+    assert r["migrations"] > 0
     assert not line["correct"]
     assert line["checks"]["max_logit_gap"]["value"] > 0.05
 

@@ -6,9 +6,9 @@ from chipbench import cells, harness
 
 READINGS = {
     "setup_s": 50.0, "window_s": 40.0, "tokens": 8000,
-    "engine_steps": 1000,
+    "engine_steps": 1000, "instances": 1,
     "row_slots_active": 6000, "row_slots_total": 8000,
-    "drafted": 400, "accepted": 100,
+    "drafted": 400, "accepted": 100, "chunks": 250, "migrations": 40,
     "latencies": [float(i) for i in range(1, 101)],
     "iterations": [{"makespan": 20.0, "finish": [1.0] * 9 + [20.0]},
                    {"makespan": 20.0, "finish": [2.0] * 10}],
@@ -24,6 +24,7 @@ WANT = {
     # iteration 1: 90% (9 of 10) done at 1.0 s of 20 s; iteration 2 at 2.0
     "tail_share": 100.0 * (19.0 + 18.0) / 40.0,
     "request_p90_s": 90.1,
+    "migration_share": 16.0,
 }
 
 
@@ -40,3 +41,16 @@ def test_reader(name):
 @pytest.mark.parametrize("name", ["device_ms_per_step", "device_idle_share"])
 def test_trace_readers_read_nothing_without_a_trace(name):
     assert harness.load_reader(name)(dict(READINGS, trace=None)) is None
+
+
+@pytest.mark.parametrize("name,want", [("step_ms", 160.0),
+                                       ("device_ms_per_step", 40.0)])
+def test_step_readers_count_the_steps_of_one_instance(name, want):
+    # four instances step side by side: 1000 steps together are 250 each
+    assert harness.load_reader(name)(dict(READINGS, instances=4)) == \
+        pytest.approx(want)
+
+
+def test_migration_share_reads_nothing_without_chunks():
+    r = dict(READINGS, chunks=0, migrations=0)
+    assert harness.load_reader("migration_share")(r) is None

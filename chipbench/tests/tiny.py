@@ -32,3 +32,14 @@ def mix() -> dict:
 
 def cell(model: dict = DENSE, limit: float = LIMIT) -> Cell:
     return Cell("tiny-dense", conf(model, limit), mix(), 1)
+
+
+# the benchmark's cell of several one-chip instances, whose metrics a
+# tiny cell of that name reports
+INSTANCES_CELL = "granite-3-8b.pp2.grpo.4x1"
+
+
+def instances_cell(chips: int = 2) -> Cell:
+    """The tiny cell as ``chips`` one-chip instances behind one scheduler,
+    under the name of the benchmark's multi-instance cell."""
+    return Cell(INSTANCES_CELL, conf(), mix(), chips)
